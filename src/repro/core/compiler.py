@@ -29,7 +29,7 @@ import time
 import warnings
 from typing import Any, Callable
 
-from repro.core import node_types
+from repro.core import node_types, tracing
 from repro.core.constraints import PFGroups
 from repro.core.cost_model import EstimatorBank, default_bank
 from repro.core.dfg import DFG
@@ -163,7 +163,11 @@ class BatchedProgram:
 
     ``stats`` counts forwards per bucket size — each distinct bucket shape
     jit-compiles exactly once (jax caches on shape), so its keys are also
-    the set of compiled entry points.
+    the set of compiled entry points.  ``phase_s`` sums the host seconds of
+    each phase of a call: ``put`` (host→device copy and pad to the bucket),
+    ``forward`` (dispatch of the jitted forward, which returns before the
+    device finishes) and ``slice`` (padding rows sliced off).  Each phase is
+    also a span of the profiler's trace (:mod:`repro.core.tracing`).
     """
 
     program: CompiledProgram
@@ -172,6 +176,9 @@ class BatchedProgram:
     fn: Callable[[dict[str, Any]], dict[str, Any]]
     exec_mode: str = "interpret"
     stats: dict[int, int] = dataclasses.field(default_factory=dict)
+    phase_s: dict[str, float] = dataclasses.field(
+        default_factory=lambda: dict.fromkeys(("put", "forward", "slice"),
+                                              0.0))
 
     @classmethod
     def build(cls, program: CompiledProgram, *, max_batch: int = 64,
@@ -191,15 +198,20 @@ class BatchedProgram:
             qplan=program.qplan, plan=program.plan, mode=exec_mode)
         if mode == "vmap":
             inner = build_callable(program.dfg, jit=False, batch=True, **kw)
-            fn = jax.jit(lambda inputs: inner(**inputs))
+
+            def forward(inputs):
+                return inner(**inputs)
         elif mode == "map":
             single = build_callable(program.dfg, jit=False, **kw)
-            fn = jax.jit(
-                lambda inputs: jax.lax.map(lambda s: single(**s), inputs))
+
+            def forward(inputs):
+                return jax.lax.map(lambda s: single(**s), inputs)
         else:
             raise ValueError(f"unknown batch mode {mode!r}")
-        return cls(program=program, max_batch=max_batch, mode=mode, fn=fn,
-                   exec_mode=exec_mode)
+        # the name of the XLA program, and so of its launches in a trace
+        forward.__name__ = f"mafia_forward_{mode}"
+        return cls(program=program, max_batch=max_batch, mode=mode,
+                   fn=jax.jit(forward), exec_mode=exec_mode)
 
     def bucket(self, n: int) -> int:
         """Smallest power-of-two ≥ n, capped at ``max_batch``."""
@@ -212,40 +224,58 @@ class BatchedProgram:
 
     def __call__(self, **inputs: Any) -> dict[str, Any]:
         import jax.numpy as jnp
+        import numpy as np
 
-        arrays = {k: jnp.asarray(v) for k, v in inputs.items()}
         allowed = set(self.program.dfg.graph_inputs)
-        unknown = set(arrays) - allowed
+        unknown = set(inputs) - allowed
         if unknown:  # mirror the per-sample path: extras are a caller bug
             raise TypeError(f"unknown graph inputs: {sorted(unknown)}")
-        missing = allowed - set(arrays)
+        missing = allowed - set(inputs)
         if missing:
             raise TypeError(f"missing graph inputs: {sorted(missing)}")
-        sizes = {v.shape[0] for v in arrays.values()}
+        sizes = {np.shape(v)[0] for v in inputs.values()}
         if len(sizes) != 1:
             raise ValueError(f"inconsistent batch sizes: {sorted(sizes)}")
         (B,) = sizes
+        phase = self.phase_s
+        arrays: dict[str, Any] | None = None
         chunks: list[dict[str, Any]] = []
         start = 0
+        t = time.perf_counter()
         while start < B:
             stop = min(start + self.max_batch, B)
             nb = stop - start
             bkt = self.bucket(nb)
             pad = bkt - nb
-            chunk = {
-                k: jnp.pad(v[start:stop], ((0, pad),) + ((0, 0),) * (v.ndim - 1))
-                for k, v in arrays.items()
-            }
-            out = self.fn(chunk)
+            with tracing.span(tracing.PUT, bucket=bkt):
+                if arrays is None:
+                    arrays = {k: jnp.asarray(v) for k, v in inputs.items()}
+                chunk = {
+                    k: jnp.pad(v[start:stop],
+                               ((0, pad),) + ((0, 0),) * (v.ndim - 1))
+                    for k, v in arrays.items()
+                }
+            t1 = time.perf_counter()
+            with tracing.span(tracing.FORWARD, bucket=bkt):
+                out = self.fn(chunk)
+            t2 = time.perf_counter()
             self.stats[bkt] = self.stats.get(bkt, 0) + 1
-            chunks.append({k: v[:nb] for k, v in out.items()})
+            with tracing.span(tracing.SLICE):
+                chunks.append({k: v[:nb] for k, v in out.items()})
+            phase["put"] += t1 - t
+            phase["forward"] += t2 - t1
+            t = time.perf_counter()
+            phase["slice"] += t - t2
             start = stop
         if len(chunks) == 1:
             return chunks[0]
-        return {
-            k: jnp.concatenate([c[k] for c in chunks], axis=0)
-            for k in chunks[0]
-        }
+        with tracing.span(tracing.SLICE):
+            joined = {
+                k: jnp.concatenate([c[k] for c in chunks], axis=0)
+                for k in chunks[0]
+            }
+        phase["slice"] += time.perf_counter() - t
+        return joined
 
 
 # stale-calibration warnings fire once per table per process — a fleet of

@@ -164,21 +164,24 @@ def _interpret(
                 env[nid] = None
             env[step.terminal] = val
 
-    def exec_segment(seg: Any, env: dict[str, Any], bdim: int | None) -> None:
-        """Run one megakernel segment (single launch) and publish its stored
-        refs.  The batched lane vmaps the whole launch over the bucket."""
+    def exec_segment(seg: Any, index: int, env: dict[str, Any],
+                     bdim: int | None) -> None:
+        """Run segment ``index`` of the program (single launch) and publish
+        its stored refs.  The batched lane vmaps the whole launch over the
+        bucket."""
         args = [env[r] for r in seg.in_refs]
         if batch and args:
             if grid:
                 # batch-grid lane: the bucket rides the Pallas grid — one
                 # launch per segment per bucket, matrices DMA'd once.
-                outs = run_segment_grid(seg, args)
+                outs = run_segment_grid(seg, args, index=index)
             else:
-                outs = jax.vmap(lambda *a: tuple(run_segment(seg, a)))(*args)
+                outs = jax.vmap(lambda *a: tuple(
+                    run_segment(seg, a, index=index)))(*args)
             for i, r in enumerate(seg.out_refs):
                 env[r] = outs[i].reshape((bdim,) + seg.out_shapes[i])
         else:
-            outs = run_segment(seg, args)
+            outs = run_segment(seg, args, index=index)
             for i, r in enumerate(seg.out_refs):
                 val = outs[i].reshape(seg.out_shapes[i])
                 if batch and bdim is not None:
@@ -205,9 +208,11 @@ def _interpret(
         bdim = next((v.shape[0] for v in env.values()), None) if batch else None
 
         if mk:
+            index = 0
             for kind, payload in plan.megakernel.items:
                 if kind == "seg":
-                    exec_segment(payload, env, bdim)
+                    exec_segment(payload, index, env, bdim)
+                    index += 1
                 else:   # interpreted island: a step with no ISA encoding
                     exec_step(plan.steps[payload], env, bdim)
         else:

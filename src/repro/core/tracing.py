@@ -1,0 +1,40 @@
+"""Host spans of the serving path, on the JAX profiler's clock.
+
+A span is a ``jax.profiler.TraceAnnotation``: it lands in the profiler's
+own trace beside the device's operations, and it is recorded only while a
+profiler session runs (``jax.profiler.trace(dir)``).  With no session a span
+costs about a microsecond.  There is no buffer, exporter or switch here.
+
+Stats ride as keyword arguments (``span(FLUSH, tenant="a", n=3)``) and show
+in the trace as the event's stats.  Every name starts with ``mafia.``.
+"""
+
+from __future__ import annotations
+
+from jax.profiler import TraceAnnotation
+
+__all__ = ["span", "mark", "POLL", "FLUSH", "RESTORE", "STACK", "PUT",
+           "FORWARD", "SLICE", "FETCH", "SCATTER", "GC", "COMPILE"]
+
+POLL = "mafia.poll"                  # one scheduling round
+FLUSH = "mafia.flush"                # one batched forward of one tenant
+RESTORE = "mafia.flush.restore"      # artifact-store load or recompile
+STACK = "mafia.flush.stack"          # rows stacked into one array
+PUT = "mafia.batch.put"              # host->device copy and pad to bucket
+FORWARD = "mafia.batch.forward"      # dispatch of the jitted forward
+SLICE = "mafia.batch.slice"          # padding rows sliced off
+FETCH = "mafia.flush.fetch"          # device wait and device->host copy
+SCATTER = "mafia.flush.scatter"      # answers, futures and counters
+GC = "mafia.gc"                      # an interpreter collection
+COMPILE = "mafia.compile"            # an XLA program built or loaded
+
+
+def span(name: str, **stats) -> TraceAnnotation:
+    """A host span named ``name`` with ``stats``; use it with ``with``."""
+    return TraceAnnotation(name, **stats)
+
+
+def mark(name: str, **stats) -> None:
+    """A zero-length span: an instant worth finding in the trace."""
+    with TraceAnnotation(name, **stats):
+        pass

@@ -383,7 +383,13 @@ def _launch_pools(seg: MegakernelSegment):
     return crows, mats
 
 
-def _build_launch(seg: MegakernelSegment, interpret: bool):
+def _launch_name(index: int, grid: bool) -> str:
+    """The name of a segment's kernel and of its jitted launch, by lane
+    and segment: what the device trace calls its operation."""
+    return f"mafia_{'grid_' if grid else ''}seg{index}"
+
+
+def _build_launch(seg: MegakernelSegment, interpret: bool, index: int):
     """Build (once per segment) the jitted single-launch callable.
 
     The instruction stream, const pool and matrix operands are static — the
@@ -410,6 +416,7 @@ def _build_launch(seg: MegakernelSegment, interpret: bool):
                    for w, dt in zip(seg.out_widths, out_dts)],
         scratch_shapes=scratch,
         interpret=interpret,
+        name=_launch_name(index, False),
     )
 
     def launch(*xs):
@@ -418,10 +425,12 @@ def _build_launch(seg: MegakernelSegment, interpret: bool):
             outs = [outs]
         return [o[0] for o in outs]
 
+    launch.__name__ = _launch_name(index, False)
     return jax.jit(launch)
 
 
-def _build_launch_grid(seg: MegakernelSegment, interpret: bool, nb: int):
+def _build_launch_grid(seg: MegakernelSegment, interpret: bool, nb: int,
+                       index: int):
     """Build the batch-grid launch: ``grid=(nb,)``, one kernel invocation
     per sample, matrices DMA'd into VMEM once on grid step 0 and shared by
     every later step (grid steps are sequential on the same core).  This is
@@ -466,6 +475,7 @@ def _build_launch_grid(seg: MegakernelSegment, interpret: bool, nb: int):
                    for w in seg.out_widths],
         scratch_shapes=scratch,
         interpret=interpret,
+        name=_launch_name(index, True),
     )
 
     def launch(*xs):
@@ -474,16 +484,17 @@ def _build_launch_grid(seg: MegakernelSegment, interpret: bool, nb: int):
             outs = [outs]
         return [o.reshape(nb, -1) for o in outs]
 
+    launch.__name__ = _launch_name(index, True)
     return jax.jit(launch)
 
 
 def _cached_launch(seg: MegakernelSegment, interpret: bool,
-                   nb: int | None = None):
+                   nb: int | None = None, index: int = 0):
     key = (id(seg), interpret, nb)
     fn = _launch_cache.get(key)
     if fn is None:
-        fn = (_build_launch(seg, interpret) if nb is None
-              else _build_launch_grid(seg, interpret, nb))
+        fn = (_build_launch(seg, interpret, index) if nb is None
+              else _build_launch_grid(seg, interpret, nb, index))
         _launch_cache[key] = fn
         sid = id(seg)
         weakref.finalize(
@@ -499,6 +510,7 @@ def run_segment(
     inputs: Sequence[jax.Array],
     *,
     interpret: bool | None = None,
+    index: int = 0,
 ) -> list[jax.Array]:
     """Execute one segment in a single ``pallas_call``.
 
@@ -507,11 +519,12 @@ def run_segment(
     ``seg.out_refs`` (the caller reshapes via ``seg.out_shapes``).  Int-lane
     inputs may be narrow or int32; outputs are the narrow activation dtype.
     The launch is traced once per segment and cached (the stream is static),
-    so repeated eager calls pay only one XLA dispatch.
+    so repeated eager calls pay only one XLA dispatch.  ``index``, the
+    segment's place in its program, names the launch (``mafia_seg<index>``).
     """
     interpret = resolve_interpret(interpret)
     xs = [jnp.asarray(x).reshape(1, -1) for x in inputs]
-    return _cached_launch(seg, interpret)(*xs)
+    return _cached_launch(seg, interpret, index=index)(*xs)
 
 
 def run_segment_grid(
@@ -519,6 +532,7 @@ def run_segment_grid(
     inputs: Sequence[jax.Array],
     *,
     interpret: bool | None = None,
+    index: int = 0,
 ) -> list[jax.Array]:
     """Execute one segment for a whole bucket in a single ``pallas_call``.
 
@@ -528,9 +542,10 @@ def run_segment_grid(
     selected by ``program_id``, and matrix DMAs issued only on grid step 0
     so every matrix crosses HBM→VMEM once per bucket.  Returns one
     ``(nb, width)`` value per ``seg.out_refs``; bitwise identical to
-    ``jax.vmap(run_segment)`` on every lane.
+    ``jax.vmap(run_segment)`` on every lane.  The launch is named
+    ``mafia_grid_seg<index>``.
     """
     interpret = resolve_interpret(interpret)
     nb = int(jnp.asarray(inputs[0]).shape[0])
     xs = [jnp.asarray(x).reshape(nb, -1) for x in inputs]
-    return _cached_launch(seg, interpret, nb)(*xs)
+    return _cached_launch(seg, interpret, nb, index)(*xs)

@@ -26,7 +26,11 @@ forward:
   re-running Best-PF — on its next request.
 * **Metrics**: per-model and engine-wide
   :class:`~repro.serve.metrics.ServeMetrics` — enqueue→complete p50/p99,
-  rps, batch occupancy, SLO misses, artifact cache hits/misses.
+  rps, batch occupancy, SLO misses, artifact cache hits/misses, host
+  seconds by flush phase, flushes by reason and how late each came.
+* **Spans**: each poll, flush and phase of a flush, and each collector
+  pause while :meth:`run` serves, is a span of the JAX profiler's trace
+  (:mod:`repro.core.tracing`), on the clock of the device's operations.
 
 The scheduling core is deliberately **synchronous and clock-injectable**:
 ``submit`` / ``poll`` / ``flush`` take an explicit ``now`` and never sleep,
@@ -40,13 +44,16 @@ deterministic.  The asyncio surface — ``submit_async`` / ``result`` /
 from __future__ import annotations
 
 import asyncio
+import gc
 import time
 from typing import Any, Callable
 
 import numpy as np
 
+from repro.core import tracing
 from repro.serve.metrics import ServeMetrics
-from repro.serve.scheduling import AdmissionQueue, InferRequest, QueueFull
+from repro.serve.scheduling import (AdmissionQueue, InferRequest, QueueFull,
+                                    bucket_for)
 
 __all__ = ["AsyncServeEngine", "ModelState"]
 
@@ -78,6 +85,10 @@ class ModelState:
         # rolling estimate of one batched forward's wall time — the SLO
         # margin: flush when deadline - now <= this, or we'd miss it
         self.est_batch_s = 0.0
+        # why the next flush runs and when the policy made the queue due;
+        # set by ``poll``, read once by ``flush`` (a direct call: "call")
+        self.flush_reason = "call"
+        self.due_at: float | None = None
 
     @property
     def resident(self) -> bool:
@@ -123,6 +134,7 @@ class AsyncServeEngine:
         self._tick = 0                       # LRU counter
         self._running = False
         self._wake: asyncio.Event | None = None
+        self._gc_open: tuple[Any, float] | None = None   # span, start
 
     # ----------------------------------------------------------- registration
     def register_model(
@@ -236,27 +248,37 @@ class AsyncServeEngine:
         state.metrics.evictions += 1
         self.metrics.evictions += 1
 
-    def _ensure_resident(self, state: ModelState) -> None:
+    def _ensure_resident(self, state: ModelState) -> float:
+        """Bring ``state``'s program back if it was evicted; returns the
+        seconds that took (0 when it was resident)."""
         if state.resident:
             state.last_used = self._tick
-            return
-        prog = None
-        if self.artifact_store is not None and state.art_key is not None:
-            before = (self.artifact_store.hits, self.artifact_store.misses)
-            prog = self.artifact_store.load(state.art_key)
-            hit = self.artifact_store.hits > before[0]
-            for m in (state.metrics, self.metrics):
-                if hit:
-                    m.cache_hits += 1
-                else:
-                    m.cache_misses += 1
-        if prog is None:
-            if state.loader is None:
-                raise RuntimeError(
-                    f"model {state.name!r} was evicted and has no loader "
-                    f"or artifact to restore from")
-            prog = state.loader()
-        self._make_resident(state, prog)
+            return 0.0
+        t0 = time.perf_counter()
+        sp = tracing.span(tracing.RESTORE)
+        with sp:
+            prog = None
+            hit = False
+            if self.artifact_store is not None and state.art_key is not None:
+                before = self.artifact_store.hits
+                prog = self.artifact_store.load(state.art_key)
+                hit = self.artifact_store.hits > before
+                for m in (state.metrics, self.metrics):
+                    if hit:
+                        m.cache_hits += 1
+                    else:
+                        m.cache_misses += 1
+            if prog is None:
+                if state.loader is None:
+                    raise RuntimeError(
+                        f"model {state.name!r} was evicted and has no loader "
+                        f"or artifact to restore from")
+                prog = state.loader()
+            self._make_resident(state, prog)
+            sp.set_metadata(hit=int(hit))
+        for m in (state.metrics, self.metrics):
+            m.restores += 1
+        return time.perf_counter() - t0
 
     # -------------------------------------------------------------- admission
     def submit(self, model: str, x: np.ndarray, *,
@@ -291,35 +313,68 @@ class AsyncServeEngine:
     def flush(self, model: str, n: int | None = None) -> list[InferRequest]:
         """Drain up to ``n`` (default: one full bucket) queued requests of
         ``model`` through one batched forward.  The device path is exactly
-        the sync engine's: stack → pad-to-bucket → jit forward → scatter."""
+        the sync engine's: stack → pad-to-bucket → jit forward → scatter.
+
+        The flush is a ``mafia.flush`` span with its phases as child spans;
+        its stats are the tenant, the rows and bucket, why it ran
+        (``full``/``slo``/``wait`` from :meth:`poll`, ``force`` from a
+        drain, ``call`` otherwise), how long the oldest row waited and how
+        late the flush came after the policy made the queue due (engine
+        clock, µs)."""
         state = self._model(model)
         if not state.queue:
             return []
-        self._tick += 1
-        self._ensure_resident(state)
-        batch = state.queue.take(state.max_batch if n is None else n)
-        X = np.stack([r.x for r in batch])
-        t0 = time.perf_counter()
-        out = state.batched(**{state.input_name: X})
-        out = {k: np.asarray(v) for k, v in out.items()}
-        dev = time.perf_counter() - t0
-        # rolling one-batch latency estimate drives the SLO flush margin
-        state.est_batch_s = (dev if state.est_batch_s == 0.0
-                             else 0.5 * state.est_batch_s + 0.5 * dev)
-        done = self.clock()
-        for i, req in enumerate(batch):
-            req.outputs = {k: v[i] for k, v in out.items()}
-            req.output_names = state.output_names
-            req.t_done = done
-            missed = req.deadline is not None and done > req.deadline
-            for m in (state.metrics, self.metrics):
-                m.record_request(done - req.t_submit, t_submit=req.t_submit,
-                                 t_done=done, missed_slo=missed)
-            state.finished.append(req)
-            if req.future is not None and not req.future.done():
-                req.future.set_result(req)
-        for m in (state.metrics, self.metrics):
-            m.record_batch(len(batch), dev)
+        reason, due_at = state.flush_reason, state.due_at
+        state.flush_reason, state.due_at = "call", None
+        start = self.clock()
+        take = state.max_batch if n is None else n
+        rows = min(len(state.queue), take)
+        wait = start - state.queue.oldest().t_submit
+        late = 0.0 if due_at is None else start - due_at
+        with tracing.span(tracing.FLUSH, tenant=model, n=rows,
+                          bucket=bucket_for(rows, state.max_batch),
+                          reason=reason, wait_us=wait * 1e6,
+                          late_us=late * 1e6):
+            self._tick += 1
+            restore = self._ensure_resident(state)
+            t1 = time.perf_counter()
+            with tracing.span(tracing.STACK):
+                batch = state.queue.take(take)
+                X = np.stack([r.x for r in batch])
+            t2 = time.perf_counter()
+            lane = state.batched.phase_s
+            put, fwd, slc = lane["put"], lane["forward"], lane["slice"]
+            out = state.batched(**{state.input_name: X})
+            t3 = time.perf_counter()
+            with tracing.span(tracing.FETCH):
+                out = {k: np.asarray(v) for k, v in out.items()}
+            t4 = time.perf_counter()
+            dev = t4 - t2
+            # rolling one-batch latency estimate drives the SLO flush margin
+            state.est_batch_s = (dev if state.est_batch_s == 0.0
+                                 else 0.5 * state.est_batch_s + 0.5 * dev)
+            with tracing.span(tracing.SCATTER):
+                done = self.clock()
+                for i, req in enumerate(batch):
+                    req.outputs = {k: v[i] for k, v in out.items()}
+                    req.output_names = state.output_names
+                    req.t_done = done
+                    missed = req.deadline is not None and done > req.deadline
+                    for m in (state.metrics, self.metrics):
+                        m.record_request(done - req.t_submit,
+                                         t_submit=req.t_submit, t_done=done,
+                                         missed_slo=missed)
+                    state.finished.append(req)
+                    if req.future is not None and not req.future.done():
+                        req.future.set_result(req)
+                for m in (state.metrics, self.metrics):
+                    m.record_batch(len(batch), dev)
+                row = (len(batch), wait, late, restore, t2 - t1,
+                       lane["put"] - put, lane["forward"] - fwd,
+                       lane["slice"] - slc, t4 - t3,
+                       time.perf_counter() - t4)
+                for m in (state.metrics, self.metrics):
+                    m.record_flush(reason, row)
         return batch
 
     def poll(self, now: float | None = None, *,
@@ -330,12 +385,24 @@ class AsyncServeEngine:
         ``batch_wait`` exhausted.  ``force`` drains everything."""
         t = self.clock() if now is None else now
         completed: list[InferRequest] = []
-        for state in self._models.values():
-            while len(state.queue) >= state.max_batch:
-                completed.extend(self.flush(state.name))
-            if state.queue and (force or state.queue.due(
-                    t, margin=state.est_batch_s,
-                    max_wait=state.batch_wait_s)):
+        with tracing.span(tracing.POLL):
+            for state in self._models.values():
+                q = state.queue
+                while len(q) >= state.max_batch:
+                    # due when the row that filled the bucket arrived
+                    state.flush_reason = "full"
+                    state.due_at = q[state.max_batch - 1].t_submit
+                    completed.extend(self.flush(state.name))
+                if not q:
+                    continue
+                if force:
+                    state.flush_reason = "force"
+                elif q.due(t, margin=state.est_batch_s,
+                           max_wait=state.batch_wait_s):
+                    state.flush_reason, state.due_at = q.due_moment(
+                        margin=state.est_batch_s, max_wait=state.batch_wait_s)
+                else:
+                    continue
                 completed.extend(self.flush(state.name))
         return completed
 
@@ -377,11 +444,28 @@ class AsyncServeEngine:
             await asyncio.sleep(0)
         return req
 
+    def _gc_hook(self, phase: str, info: dict) -> None:
+        """``gc.callbacks`` hook: each collection a ``mafia.gc`` span and
+        a count in the engine-wide metrics."""
+        if phase == "start":
+            sp = tracing.span(tracing.GC, generation=info["generation"])
+            sp.__enter__()
+            self._gc_open = (sp, time.perf_counter())
+        elif self._gc_open is not None:
+            sp, t0 = self._gc_open
+            self._gc_open = None
+            sp.__exit__(None, None, None)
+            self.metrics.record_gc(info["generation"],
+                                   time.perf_counter() - t0)
+
     async def run(self) -> None:
         """The serving loop: poll, then sleep until the next deadline
-        horizon or a new submission wakes it.  Runs until :meth:`stop`."""
+        horizon or a new submission wakes it.  Runs until :meth:`stop`.
+        While it runs, collector pauses are spans and counters."""
         self._running = True
         self._wake = asyncio.Event()
+        hook = self._gc_hook
+        gc.callbacks.append(hook)
         try:
             while self._running:
                 self.poll()
@@ -400,6 +484,7 @@ class AsyncServeEngine:
             self._running = False
             if self.pending():                    # never strand requests
                 self.drain()
+            gc.callbacks.remove(hook)
 
     def stop(self) -> None:
         self._running = False
@@ -413,5 +498,8 @@ class AsyncServeEngine:
         snap = self.metrics.snapshot()
         snap["models"] = {n: m.metrics.snapshot()
                           for n, m in self._models.items()}
+        for n, m in self._models.items():
+            if m.resident:    # the resident batch lane's own phase sums
+                snap["models"][n]["batch_phase_s"] = dict(m.batched.phase_s)
         snap["resident"] = list(self.resident_models)
         return snap

@@ -2,16 +2,17 @@
 
 Every engine (sync adapter and async tier) funnels its observations through
 one :class:`ServeMetrics` instance per model plus one engine-wide aggregate:
-``record_batch`` after each batched forward (batch size, bucket, device
-seconds) and ``record_request`` at each request completion (enqueue→complete
-latency, SLO verdict).  ``snapshot()`` reduces them to the numbers the
-benchmarks gate on — p50/p99 latency, requests/sec, mean batch occupancy —
-plus the compile-artifact cache hit/miss counters the cold-start story is
-measured by.
+``record_batch`` after each batched forward (batch size, host seconds of
+dispatch plus fetch), ``record_flush`` with the host seconds of each phase
+of that flush and why it ran, and ``record_request`` at each request
+completion (enqueue→complete latency, SLO verdict).  ``snapshot()`` reduces
+them to the numbers the benchmarks gate on — p50/p99 latency, requests/sec,
+mean batch occupancy — plus the compile-artifact cache hit/miss counters the
+cold-start story is measured by, and the host seconds by phase.
 
-Latencies are kept in a bounded reservoir (default 8192): old observations
-are dropped FIFO, so long-running engines report *recent* percentiles at
-O(1) memory.
+Latencies and flushes are kept in bounded reservoirs (default 8192): old
+observations are dropped FIFO, so long-running engines report *recent*
+percentiles at O(1) memory.
 """
 
 from __future__ import annotations
@@ -21,7 +22,15 @@ from typing import Iterable
 
 import numpy as np
 
-__all__ = ["ServeMetrics", "percentile"]
+__all__ = ["ServeMetrics", "percentile", "PHASES", "FLUSH_FIELDS"]
+
+# host phases of one flush, in order: restore (only when the program was not
+# resident), stack the rows, then the batch lane's put/forward/slice, fetch
+# the outputs (device wait + device->host), scatter the answers.
+PHASES = ("restore", "stack", "put", "forward", "slice", "fetch", "scatter")
+# one reservoir row per flush: rows carried, how late the loop came to the
+# queue after the policy made it due, then each phase's seconds.
+FLUSH_FIELDS = ("n", "wait_s", "late_s") + PHASES
 
 
 def percentile(values: Iterable[float], q: float) -> float:
@@ -38,9 +47,19 @@ class ServeMetrics:
             maxlen=reservoir)
         self._batch_sizes: collections.deque[int] = collections.deque(
             maxlen=reservoir)
+        self._flushes: collections.deque[tuple] = collections.deque(
+            maxlen=reservoir)
         self.served = 0               # requests completed
         self.batches = 0              # batched forwards issued
-        self.device_s = 0.0           # wall time inside batched forwards
+        # host wall time of dispatch plus fetch of each batched forward (not
+        # the device's busy time, which only a device trace shows)
+        self.device_s = 0.0
+        self.phase_s = dict.fromkeys(PHASES, 0.0)   # host seconds by phase
+        self.late_s = 0.0             # flush start - when it became due
+        self.flushes_by_reason: dict[str, int] = {}
+        self.restores = 0             # evicted programs brought back
+        self.gc_s = 0.0               # collector pauses (engine-wide only)
+        self.gc_collections: dict[int, int] = {}    # by generation
         self.slo_misses = 0           # completions past their deadline
         self.rejected = 0             # admissions refused (queue full)
         self.cache_hits = 0           # program/artifact cache hits
@@ -55,6 +74,29 @@ class ServeMetrics:
         self.served += n
         self.device_s += device_s
         self._batch_sizes.append(n)
+
+    def record_flush(self, reason: str, row: tuple) -> None:
+        """One flush: why it ran (``full``/``slo``/``wait``/``force``/
+        ``call``) and its reservoir row (:data:`FLUSH_FIELDS`)."""
+        self._flushes.append(row)
+        self.flushes_by_reason[reason] = (
+            self.flushes_by_reason.get(reason, 0) + 1)
+        self.late_s += row[2]
+        phase = self.phase_s
+        for name, secs in zip(PHASES, row[3:]):
+            phase[name] += secs
+
+    def record_gc(self, generation: int, secs: float) -> None:
+        self.gc_s += secs
+        self.gc_collections[generation] = (
+            self.gc_collections.get(generation, 0) + 1)
+
+    def recent_flushes(self) -> dict[str, np.ndarray]:
+        """The reservoir's flushes, oldest first, one array per field of
+        :data:`FLUSH_FIELDS` (seconds, or rows for ``n``)."""
+        rows = np.asarray(self._flushes, np.float64).reshape(
+            -1, len(FLUSH_FIELDS))
+        return dict(zip(FLUSH_FIELDS, rows.T))
 
     def record_request(self, latency_s: float, *, t_submit: float,
                        t_done: float, missed_slo: bool = False) -> None:
@@ -80,8 +122,9 @@ class ServeMetrics:
         return self.served / w if w > 0 else 0.0
 
     def device_rps(self) -> float:
-        """Requests/sec over device time only (the sync engines' historical
-        ``throughput()`` figure — excludes queueing)."""
+        """Requests/sec over the host wall time of dispatch plus fetch (the
+        sync engines' historical ``throughput()`` figure — excludes queueing
+        and the per-request Python around the forward)."""
         return self.served / self.device_s if self.device_s > 0 else 0.0
 
     def batch_occupancy(self) -> float:
@@ -105,6 +148,12 @@ class ServeMetrics:
             "cache_hits": self.cache_hits,
             "cache_misses": self.cache_misses,
             "evictions": self.evictions,
+            "phase_s": dict(self.phase_s),
+            "late_s": self.late_s,
+            "flushes_by_reason": dict(self.flushes_by_reason),
+            "restores": self.restores,
+            "gc_s": self.gc_s,
+            "gc_collections": dict(self.gc_collections),
         }
 
     def reset(self) -> None:

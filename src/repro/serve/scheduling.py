@@ -159,6 +159,9 @@ class AdmissionQueue:
     def __bool__(self) -> bool:
         return bool(self._items)
 
+    def __getitem__(self, i: int) -> InferRequest:
+        return self._items[i]
+
     def push(self, req: InferRequest) -> None:
         if self.limit is not None and len(self._items) >= self.limit:
             self.rejected += 1
@@ -187,6 +190,22 @@ class AdmissionQueue:
         if head.deadline is not None and head.deadline - now <= margin:
             return True
         return max_wait is not None and now - head.t_submit >= max_wait
+
+    def due_moment(self, *, margin: float = 0.0,
+                   max_wait: float | None = None) -> tuple[str, float | None]:
+        """Why and when the queue becomes (or became) due: ``("slo",
+        deadline - margin)`` or ``("wait", t_submit + max_wait)`` of the
+        oldest request, whichever comes first."""
+        head = self.oldest()
+        moments: list[tuple[float, str]] = []
+        if head is not None and head.deadline is not None:
+            moments.append((head.deadline - margin, "slo"))
+        if head is not None and max_wait is not None:
+            moments.append((head.t_submit + max_wait, "wait"))
+        if not moments:
+            return "wait", None
+        at, reason = min(moments)
+        return reason, at
 
     def next_due_in(self, now: float, *, margin: float = 0.0,
                     max_wait: float | None = None) -> float | None:

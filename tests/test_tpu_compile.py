@@ -148,3 +148,25 @@ def test_fused_linear_chain_compiles(precision, one_chip):
         x = jax.ShapeDtypeStruct((BUCKET, n), dt, sharding=one_chip)
         extras = [x] * len(step.extras)
         assert _compiled_text(fn, x, *extras).count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("name", ["bonsai/usps-b", "protonn/usps-b"])
+def test_served_forward_names_its_programs(name, one_chip, monkeypatch):
+    """The served forward compiles to an XLA program named after it, and
+    its kernel's operation after the lane and segment: the names a device
+    trace shows.  The lane picks the compiled kernel as it would on the
+    chip."""
+    from repro.core.compiler import BatchedProgram
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    prog = _program(name, "float32")
+    bp = BatchedProgram.build(prog, max_batch=BUCKET,
+                              exec_mode="megakernel_grid")
+    (inp,) = prog.dfg.graph_inputs
+    arg = {inp: jax.ShapeDtypeStruct(
+        (BUCKET,) + tuple(prog.dfg.graph_inputs[inp].shape), jnp.float32,
+        sharding=one_chip)}
+    text = bp.fn.lower(arg).compile().as_text()
+    assert text.startswith("HloModule jit_mafia_forward_vmap")
+    (call,) = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert call.lstrip().startswith("%mafia_grid_seg0")
