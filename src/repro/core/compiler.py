@@ -161,13 +161,22 @@ class CompiledProgram:
 class BatchedProgram:
     """Bucketed, jit-cached batched callable over a :class:`CompiledProgram`.
 
+    A call crosses the host↔device boundary once each way: each chunk is
+    zero-padded to its bucket in numpy and handed to the jitted forward,
+    whose dispatch copies it to the device; the outputs of every chunk are
+    fetched together, with one wait, and the padding rows are sliced off
+    on the host, so the call returns numpy.
+
     ``stats`` counts forwards per bucket size — each distinct bucket shape
     jit-compiles exactly once (jax caches on shape), so its keys are also
-    the set of compiled entry points.  ``phase_s`` sums the host seconds of
-    each phase of a call: ``put`` (host→device copy and pad to the bucket),
-    ``forward`` (dispatch of the jitted forward, which returns before the
-    device finishes) and ``slice`` (padding rows sliced off).  Each phase is
-    also a span of the profiler's trace (:mod:`repro.core.tracing`).
+    the set of compiled entry points.  ``host_pad_rows`` counts the
+    padding rows added on the host.  ``phase_s`` sums the host seconds of
+    each phase of a call: ``put`` (pad to the bucket in numpy), ``forward``
+    (dispatch of the jitted forward, with the host→device copy; it returns
+    before the device finishes), ``fetch`` (the device wait and one
+    device→host copy of every output) and ``slice`` (padding rows sliced
+    off, chunks joined).  Each phase is also a span of the profiler's
+    trace (:mod:`repro.core.tracing`).
     """
 
     program: CompiledProgram
@@ -176,9 +185,10 @@ class BatchedProgram:
     fn: Callable[[dict[str, Any]], dict[str, Any]]
     exec_mode: str = "interpret"
     stats: dict[int, int] = dataclasses.field(default_factory=dict)
+    host_pad_rows: int = 0
     phase_s: dict[str, float] = dataclasses.field(
-        default_factory=lambda: dict.fromkeys(("put", "forward", "slice"),
-                                              0.0))
+        default_factory=lambda: dict.fromkeys(
+            ("put", "forward", "fetch", "slice"), 0.0))
 
     @classmethod
     def build(cls, program: CompiledProgram, *, max_batch: int = 64,
@@ -223,7 +233,7 @@ class BatchedProgram:
         return min(b, self.max_batch)
 
     def __call__(self, **inputs: Any) -> dict[str, Any]:
-        import jax.numpy as jnp
+        import jax
         import numpy as np
 
         allowed = set(self.program.dfg.graph_inputs)
@@ -239,40 +249,45 @@ class BatchedProgram:
         (B,) = sizes
         phase = self.phase_s
         arrays: dict[str, Any] | None = None
-        chunks: list[dict[str, Any]] = []
-        start = 0
+        outs: list[dict[str, Any]] = []
+        rows: list[int] = []
         t = time.perf_counter()
-        while start < B:
+        for start in range(0, B, self.max_batch):
             stop = min(start + self.max_batch, B)
             nb = stop - start
             bkt = self.bucket(nb)
             pad = bkt - nb
             with tracing.span(tracing.PUT, bucket=bkt):
                 if arrays is None:
-                    arrays = {k: jnp.asarray(v) for k, v in inputs.items()}
+                    arrays = {k: np.asarray(v) for k, v in inputs.items()}
                 chunk = {
-                    k: jnp.pad(v[start:stop],
-                               ((0, pad),) + ((0, 0),) * (v.ndim - 1))
+                    k: np.pad(v[start:stop],
+                              ((0, pad),) + ((0, 0),) * (v.ndim - 1))
+                    if pad else v[start:stop]
                     for k, v in arrays.items()
                 }
             t1 = time.perf_counter()
             with tracing.span(tracing.FORWARD, bucket=bkt):
-                out = self.fn(chunk)
+                outs.append(self.fn(chunk))
             t2 = time.perf_counter()
             self.stats[bkt] = self.stats.get(bkt, 0) + 1
-            with tracing.span(tracing.SLICE):
-                chunks.append({k: v[:nb] for k, v in out.items()})
+            self.host_pad_rows += pad
+            rows.append(nb)
             phase["put"] += t1 - t
             phase["forward"] += t2 - t1
-            t = time.perf_counter()
-            phase["slice"] += t - t2
-            start = stop
-        if len(chunks) == 1:
-            return chunks[0]
+            t = t2
+        with tracing.span(tracing.FETCH):
+            # every output's copy starts before the one wait
+            outs = jax.device_get(outs)
+        t1 = time.perf_counter()
+        phase["fetch"] += t1 - t
+        t = t1
         with tracing.span(tracing.SLICE):
-            joined = {
-                k: jnp.concatenate([c[k] for c in chunks], axis=0)
-                for k in chunks[0]
+            parts = [{k: v[:nb] for k, v in out.items()}
+                     for out, nb in zip(outs, rows)]
+            joined = parts[0] if len(parts) == 1 else {
+                k: np.concatenate([p[k] for p in parts], axis=0)
+                for k in parts[0]
             }
         phase["slice"] += time.perf_counter() - t
         return joined

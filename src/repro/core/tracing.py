@@ -14,16 +14,16 @@ from __future__ import annotations
 from jax.profiler import TraceAnnotation
 
 __all__ = ["span", "mark", "POLL", "FLUSH", "RESTORE", "STACK", "PUT",
-           "FORWARD", "SLICE", "FETCH", "SCATTER", "GC", "COMPILE"]
+           "FORWARD", "FETCH", "SLICE", "SCATTER", "GC", "COMPILE"]
 
 POLL = "mafia.poll"                  # one scheduling round
 FLUSH = "mafia.flush"                # one batched forward of one tenant
 RESTORE = "mafia.flush.restore"      # artifact-store load or recompile
 STACK = "mafia.flush.stack"          # rows stacked into one array
-PUT = "mafia.batch.put"              # host->device copy and pad to bucket
-FORWARD = "mafia.batch.forward"      # dispatch of the jitted forward
-SLICE = "mafia.batch.slice"          # padding rows sliced off
-FETCH = "mafia.flush.fetch"          # device wait and device->host copy
+PUT = "mafia.batch.put"              # pad to the bucket (numpy, on the host)
+FORWARD = "mafia.batch.forward"      # jitted forward's dispatch, host->device
+FETCH = "mafia.flush.fetch"          # device wait, every output's copy at once
+SLICE = "mafia.batch.slice"          # padding rows sliced off on the host
 SCATTER = "mafia.flush.scatter"      # answers, futures and counters
 GC = "mafia.gc"                      # an interpreter collection
 COMPILE = "mafia.compile"            # an XLA program built or loaded

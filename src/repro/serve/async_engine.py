@@ -313,7 +313,8 @@ class AsyncServeEngine:
     def flush(self, model: str, n: int | None = None) -> list[InferRequest]:
         """Drain up to ``n`` (default: one full bucket) queued requests of
         ``model`` through one batched forward.  The device path is exactly
-        the sync engine's: stack → pad-to-bucket → jit forward → scatter.
+        the sync engine's: stack → pad to the bucket on the host → jit
+        forward → one fetch of every output → scatter.
 
         The flush is a ``mafia.flush`` span with its phases as child spans;
         its stats are the tenant, the rows and bucket, why it ran
@@ -343,11 +344,9 @@ class AsyncServeEngine:
                 X = np.stack([r.x for r in batch])
             t2 = time.perf_counter()
             lane = state.batched.phase_s
-            put, fwd, slc = lane["put"], lane["forward"], lane["slice"]
+            before = dict(lane)
+            # numpy rows in, numpy answers out: the lane fetches them itself
             out = state.batched(**{state.input_name: X})
-            t3 = time.perf_counter()
-            with tracing.span(tracing.FETCH):
-                out = {k: np.asarray(v) for k, v in out.items()}
             t4 = time.perf_counter()
             dev = t4 - t2
             # rolling one-batch latency estimate drives the SLO flush margin
@@ -370,8 +369,10 @@ class AsyncServeEngine:
                 for m in (state.metrics, self.metrics):
                     m.record_batch(len(batch), dev)
                 row = (len(batch), wait, late, restore, t2 - t1,
-                       lane["put"] - put, lane["forward"] - fwd,
-                       lane["slice"] - slc, t4 - t3,
+                       lane["put"] - before["put"],
+                       lane["forward"] - before["forward"],
+                       lane["slice"] - before["slice"],
+                       lane["fetch"] - before["fetch"],
                        time.perf_counter() - t4)
                 for m in (state.metrics, self.metrics):
                     m.record_flush(reason, row)
@@ -499,7 +500,8 @@ class AsyncServeEngine:
         snap["models"] = {n: m.metrics.snapshot()
                           for n, m in self._models.items()}
         for n, m in self._models.items():
-            if m.resident:    # the resident batch lane's own phase sums
+            if m.resident:    # the resident batch lane's own counters
                 snap["models"][n]["batch_phase_s"] = dict(m.batched.phase_s)
+                snap["models"][n]["host_pad_rows"] = m.batched.host_pad_rows
         snap["resident"] = list(self.resident_models)
         return snap

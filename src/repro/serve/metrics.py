@@ -24,9 +24,12 @@ import numpy as np
 
 __all__ = ["ServeMetrics", "percentile", "PHASES", "FLUSH_FIELDS"]
 
-# host phases of one flush, in order: restore (only when the program was not
-# resident), stack the rows, then the batch lane's put/forward/slice, fetch
-# the outputs (device wait + device->host), scatter the answers.
+# host phases of one flush: restore (only when the program was not resident),
+# stack the rows, then the batch lane's put (pad on the host) and forward
+# (dispatch with the host->device copy), fetch (device wait + one
+# device->host copy of every output) and slice (padding rows off), then
+# scatter the answers.  The lane fetches before it slices: the fields below
+# are not in the order the phases run.
 PHASES = ("restore", "stack", "put", "forward", "slice", "fetch", "scatter")
 # one reservoir row per flush: rows carried, how late the loop came to the
 # queue after the policy made it due, then each phase's seconds.

@@ -25,8 +25,9 @@ from repro.serve.metrics import PHASES
 
 from test_async_engine import BENCH, FakeClock, _requests
 
-CHILDREN = (tracing.STACK, tracing.PUT, tracing.FORWARD, tracing.SLICE,
-            tracing.FETCH, tracing.SCATTER)
+# the batch lane fetches every output before it slices on the host
+CHILDREN = (tracing.STACK, tracing.PUT, tracing.FORWARD, tracing.FETCH,
+            tracing.SLICE, tracing.SCATTER)
 
 
 def _trace(tmp_path, fn):
@@ -161,10 +162,11 @@ def test_phases_sum_within_the_flush_wall_time():
         sum(phases.values()))
     # the batch lane's own counters agree with the engine's phases
     now = eng._models["m"].batched.phase_s
-    for k in ("put", "forward", "slice"):
+    for k in ("put", "forward", "fetch", "slice"):
         assert now[k] - lane[k] == pytest.approx(phases[k])
     stats = eng.stats()["models"]["m"]
-    assert set(stats["batch_phase_s"]) == {"put", "forward", "slice"}
+    assert set(stats["batch_phase_s"]) == {"put", "forward", "fetch",
+                                           "slice"}
     assert set(stats["phase_s"]) == set(PHASES)
 
 
