@@ -31,7 +31,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, default=5.0)
     args = ap.parse_args(argv)
     bench_run._paths()
-    from harness import cell, spec
+    from harness import spec
 
     bench = spec.benchmark()
     c = spec.cell(bench, args.workload)
@@ -41,15 +41,16 @@ def main(argv=None) -> int:
     enable_compile_cache()
     cfg = spec.config(bench, c["config"])
     traffic = spec.traffic(c["traffic"])
-    su = cell.set_up(cfg, device)
+    path = spec.path(cfg)
+    su = path.set_up(cfg, device)
     lows = {k: 0.0 for k in cfg["limits"]}
     highs = {k: float("inf") for k in cfg["limits"]}
     for seed in (int(s) for s in args.seeds.split(",")):
-        pool = cell.rows(su, seed)
-        r = cell.measure(su, c, traffic, pool, seed, args.seconds, False,
+        pool = path.rows(su, seed)
+        r = path.measure(su, c, traffic, pool, seed, args.seconds, False,
                          T_START)
-        prog, attempted, _ = cell.numbers(r, pool)
-        ctl, _, _ = cell.numbers(r, pool, control=True)
+        prog, attempted, _ = path.numbers(r, pool)
+        ctl, _, _ = path.numbers(r, pool, control=True)
         for k in cfg["limits"]:
             lows[k] = max(lows[k], prog[k])
             highs[k] = min(highs[k], ctl[k])

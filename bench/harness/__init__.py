@@ -1,2 +1,4 @@
-"""The benchmark's harness: cell lookup, fleet set-up, the measured window,
-the trace reduction, the work count, and the check that decides ``correct``."""
+"""The benchmark's harness: cell, configuration and serving-path lookup,
+the traced window, the fleet path's helpers, the trace reduction, the work
+count, and the check that decides ``correct``.  The serving paths
+themselves are in ``paths/``."""

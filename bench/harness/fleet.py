@@ -71,8 +71,7 @@ def _output_keys(dfg) -> tuple[str, str]:
 
 def warm(engine, tenants: list[Tenant], max_batch: int) -> None:
     """Serve every batch size 1..max_batch of every tenant once through
-    ``flush``, so that no bucket program and no pad or slice of the batch
-    lane compiles inside the window."""
+    ``flush``, so that no bucket program compiles inside the window."""
     import jax
 
     for t in tenants:

@@ -1,12 +1,14 @@
-"""Cells, configurations, traffic mixes and metrics, found by name.
+"""Cells, configurations, serving paths, traffic mixes and metrics, found
+by name.
 
 Nothing here knows a cell: everything comes from ``BENCHMARK.json`` and the
-files it names, so a configuration, a traffic mix or a metric is added by
-adding its file and its entry.
+files it names, so a configuration, a serving path, a traffic mix or a
+metric is added by adding its file and its entry.
 """
 
 from __future__ import annotations
 
+import importlib
 import importlib.util
 import json
 from pathlib import Path
@@ -37,6 +39,18 @@ def config(bench: dict, name: str) -> dict:
     if cfg.get("name") != name:
         raise ValueError(f"config file of {name!r} names {cfg.get('name')!r}")
     return cfg
+
+
+def path(cfg: dict):
+    """The module of the serving path the configuration names under
+    ``"path"`` (``fleet`` where it names none): ``paths/<name>.py``, as
+    ``paths/__init__.py`` sets out.  An unknown path raises ``KeyError``."""
+    import paths
+
+    name = cfg.get("path", "fleet")
+    if not any((Path(d) / f"{name}.py").is_file() for d in paths.__path__):
+        raise KeyError(f"no path named {name!r}")
+    return importlib.import_module(f"paths.{name}")
 
 
 def traffic(name: str) -> dict:

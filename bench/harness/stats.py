@@ -35,8 +35,10 @@ def window_flushes(run) -> np.ndarray:
 
 
 def median_flush_ms(run) -> float | None:
-    """Median host wall time of one ``flush`` in the window (stack, pad,
-    dispatch, device wait, slice, device-to-host copy, scatter)."""
+    """Median host wall time of one ``flush`` in the window: stack, the
+    numpy pad to the bucket, the forward's dispatch with the rows' copy to
+    the device, the device wait and the outputs' copy back, the slice on the
+    host, scatter."""
     m = window_flushes(run)
     if not np.any(m):
         return None

@@ -10,20 +10,11 @@ from __future__ import annotations
 
 import asyncio
 import contextlib
-import time
 
 import numpy as np
 
+from harness.session import CLOCK, span
 from repro.serve.async_engine import AsyncServeEngine
-
-CLOCK = time.perf_counter
-
-
-def span(name: str):
-    """A host span in the profiler's trace (nothing when it is off)."""
-    import jax
-
-    return jax.profiler.TraceAnnotation(name)
 
 
 class BenchEngine(AsyncServeEngine):
@@ -145,22 +136,13 @@ class Ctx:
                 raise r
 
 
-async def _markers(ctx) -> None:
-    """Zero-length spans at the window's open and close, so that the trace
-    reduction cuts the trace to the window."""
-    await asyncio.sleep(max(0.0, ctx.t0 - CLOCK()))
-    with span("bench.window_open"):
-        pass
-    await asyncio.sleep(max(0.0, ctx.t0 + ctx.seconds - CLOCK()))
-    with span("bench.window_close"):
-        pass
-
-
-async def serve(ctx, drive, traffic: dict) -> None:
-    """Run the engine's loop and the traffic until every client is done."""
+async def serve(ctx, drive, traffic: dict, sess) -> None:
+    """Run the engine's loop and the traffic until every client is done;
+    ``sess`` (a ``session.Session``) marks the window in a traced run."""
     engine = ctx.engine
     runner = asyncio.create_task(engine.run())
-    marks = asyncio.create_task(_markers(ctx)) if ctx._traced else None
+    marks = (asyncio.create_task(sess.marks(ctx.t0, ctx.seconds))
+             if sess.traced else None)
     engine.recording = True
     try:
         await drive(ctx, traffic)

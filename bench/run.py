@@ -4,10 +4,14 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 from the root of a checkout.  The cell names a configuration and a traffic
-mix in ``BENCHMARK.json``; the harness finds their files by name.  Set-up
-(JAX start, the tenants' compiles through the artifact store, every batch
-size served once) is timed as ``setup_s``; then the traffic runs for
-``--seconds``; then every answer is checked against the plain reference.
+mix in ``BENCHMARK.json``; the harness finds their files, and the serving
+path the configuration names (``bench/paths/``), by name; an unknown cell
+or path exits 1 before set-up.  Set-up is timed as ``setup_s``: on the fleet
+path, JAX's start, the tenants' compiles through the artifact store, and
+every batch size of every tenant served once, which compiles each tenant's
+forward at each bucket (the batch lane pads and slices on the host).  Then
+the traffic runs for ``--seconds``; then every answer is checked against
+the plain reference.
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics`` (the end-to-end metrics, or with
 ``--trace 1`` the per-layer ones), ``device``, with a trace ``breakdown``,
@@ -75,8 +79,9 @@ def main(argv=None) -> int:
         from harness import cell, spec
 
         bench = spec.benchmark()
-        chips = spec.cell(bench, args.workload)["chips"]
-        device = device_of(chips)
+        c = spec.cell(bench, args.workload)
+        spec.path(spec.config(bench, c["config"]))
+        device = device_of(c["chips"])
     except (RuntimeError, KeyError, ImportError, OSError) as e:
         print(f"bench: {type(e).__name__}: {e}", file=sys.stderr)
         return 1

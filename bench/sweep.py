@@ -36,7 +36,7 @@ def main(argv=None) -> int:
     bench_run._paths()
     import numpy as np
 
-    from harness import cell, spec, stats
+    from harness import spec, stats
     from harness.stats import latency_ms, quantile
 
     bench = spec.benchmark()
@@ -46,7 +46,8 @@ def main(argv=None) -> int:
 
     enable_compile_cache()
     cfg = spec.config(bench, c["config"])
-    su = cell.set_up(cfg, device)
+    path = spec.path(cfg)
+    su = path.set_up(cfg, device)
     slo = max(t["slo_ms"] for t in cfg["tenants"])
     pauses: list[float] = []
     started: list[float] = []
@@ -61,9 +62,9 @@ def main(argv=None) -> int:
     gc.callbacks.append(gc_timer)
     for k, rate in enumerate(float(r) for r in args.rates.split(",")):
         traffic = dict(spec.traffic(c["traffic"]), rate_rps=rate)
-        pool = cell.rows(su, args.seed + k)
+        pool = path.rows(su, args.seed + k)
         pauses.clear()
-        r = cell.measure(su, c, traffic, pool, args.seed + k, args.seconds,
+        r = path.measure(su, c, traffic, pool, args.seed + k, args.seconds,
                          False, T_START)
         fl_ms = (r.flushes["t1"] - r.flushes["t0"]) * 1e3
         lat = latency_ms(r)

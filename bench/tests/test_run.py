@@ -44,17 +44,18 @@ def test_result_line(run_tiny, traffic):
 def test_control_is_not_correct(config, store_in_tmp):
     """The reference in the next lower precision than the configuration
     states fails a limit on the same requests the program passes on."""
-    from harness import cell, check, spec
+    from harness import check, spec
+    from paths import fleet
 
     bench, name = tiny_bench(config, "full")
     c = spec.cell(bench, name)
     cfg = spec.config(bench, c["config"])
-    su = cell.set_up(cfg, CPU_AS_V5E)
-    pool = cell.rows(su, 2**31 + 5)
-    r = cell.measure(su, c, spec.traffic("full"), pool, 2**31 + 5, 0.5,
-                     False, time.perf_counter())
-    prog, _, _ = cell.numbers(r, pool)
-    ctl, _, _ = cell.numbers(r, pool, control=True)
+    su = fleet.set_up(cfg, CPU_AS_V5E)
+    pool = fleet.rows(su, 2**31 + 5)
+    r = fleet.measure(su, c, spec.traffic("full"), pool, 2**31 + 5, 0.5,
+                      False, time.perf_counter())
+    prog, _, _ = fleet.numbers(r, pool)
+    ctl, _, _ = fleet.numbers(r, pool, control=True)
     assert check.verdict(prog, cfg["limits"])[0]
     assert not check.verdict(ctl, cfg["limits"])[0]
 

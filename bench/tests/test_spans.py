@@ -151,14 +151,15 @@ def test_records_match_a_real_window(store_in_tmp):
     """On a whole run at test size, the engine's records line up with the
     benchmark's flushes one for one, and every new reader reads."""
     from conftest import CPU_AS_V5E, tiny_bench
-    from harness import cell, flush_records
+    from harness import flush_records
+    from paths import fleet
 
     bench, name = tiny_bench("tiny-protonn-f32", "full")
     c = spec.cell(bench, name)
-    su = cell.set_up(spec.config(bench, c["config"]), CPU_AS_V5E)
-    pool = cell.rows(su, 2**31 + 77)
-    r = cell.measure(su, c, spec.traffic("full"), pool, 2**31 + 77, 0.5,
-                     False, time.perf_counter())
+    su = fleet.set_up(spec.config(bench, c["config"]), CPU_AS_V5E)
+    pool = fleet.rows(su, 2**31 + 77)
+    r = fleet.measure(su, c, spec.traffic("full"), pool, 2**31 + 77, 0.5,
+                      False, time.perf_counter())
     w = flush_records.window(r)
     m = r.in_window(r.flushes["t0"])
     assert np.sum(m) > 0
