@@ -92,8 +92,12 @@ ARCH_IDS: list[str] = [
     "mamba2-1.3b",
 ]
 
+# served configurations beside the assigned ones: resolvable by name, not
+# part of the dry-run's shape cells
+SERVED_IDS: list[str] = ["deepseek-v2-lite"]
+
 _MODULES = {a: "repro.configs." + a.replace("-", "_").replace(".", "_")
-            for a in ARCH_IDS}
+            for a in ARCH_IDS + SERVED_IDS}
 
 _CACHE: dict[str, ArchSpec] = {}
 
@@ -101,7 +105,8 @@ _CACHE: dict[str, ArchSpec] = {}
 def get_arch(arch_id: str) -> ArchSpec:
     if arch_id not in _CACHE:
         if arch_id not in _MODULES:
-            raise KeyError(f"unknown arch {arch_id!r}; known: {ARCH_IDS}")
+            raise KeyError(f"unknown arch {arch_id!r}; known: "
+                           f"{ARCH_IDS + SERVED_IDS}")
         mod = importlib.import_module(_MODULES[arch_id])
         spec = mod.SPEC
         assert spec.arch_id == arch_id

@@ -14,7 +14,9 @@ from __future__ import annotations
 from jax.profiler import TraceAnnotation
 
 __all__ = ["span", "mark", "POLL", "FLUSH", "RESTORE", "STACK", "PUT",
-           "FORWARD", "FETCH", "SLICE", "SCATTER", "GC", "COMPILE"]
+           "FORWARD", "FETCH", "SLICE", "SCATTER", "GC", "COMPILE",
+           "LM_PREFILL", "LM_INSERT", "LM_DECODE", "LM_DECODE_FORWARD",
+           "LM_DECODE_FETCH", "LM_DECODE_SAMPLE"]
 
 POLL = "mafia.poll"                  # one scheduling round
 FLUSH = "mafia.flush"                # one batched forward of one tenant
@@ -27,6 +29,13 @@ SLICE = "mafia.batch.slice"          # padding rows sliced off on the host
 SCATTER = "mafia.flush.scatter"      # answers, futures and counters
 GC = "mafia.gc"                      # an interpreter collection
 COMPILE = "mafia.compile"            # an XLA program built or loaded
+# the token engine (repro.serve.engine.ServeEngine)
+LM_PREFILL = "mafia.lm.prefill"      # one prompt's forward and first token
+LM_INSERT = "mafia.lm.insert"        # its cache rows written into the slot
+LM_DECODE = "mafia.lm.decode"        # one batched decode step
+LM_DECODE_FORWARD = "mafia.lm.decode.forward"   # the step's dispatch
+LM_DECODE_FETCH = "mafia.lm.decode.fetch"       # device wait, tokens copied
+LM_DECODE_SAMPLE = "mafia.lm.decode.sample"     # tokens to requests, retire
 
 
 def span(name: str, **stats) -> TraceAnnotation:

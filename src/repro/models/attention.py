@@ -29,7 +29,7 @@ from repro.models.layers import Initializer, apply_rope, he_init
 
 __all__ = [
     "init_gqa", "gqa_prefill", "gqa_decode",
-    "init_mla", "mla_prefill", "mla_decode",
+    "init_mla", "mla_prefill", "mla_decode_inputs", "mla_decode_attend",
     "flash_attention", "plain_attention",
 ]
 
@@ -287,14 +287,15 @@ def init_mla(
     return p
 
 
-def _mla_q(p: dict[str, Any], x: jax.Array) -> tuple[jax.Array, jax.Array]:
+def _mla_q(p: dict[str, Any], x: jax.Array,
+           eps: float = 1e-5) -> tuple[jax.Array, jax.Array]:
     from repro.models.layers import rms_norm
 
     dt = x.dtype
     if "w_dq" in p:
         cq = jnp.einsum("bsd,dr->bsr", x, p["w_dq"].astype(dt),
                         preferred_element_type=jnp.float32).astype(dt)
-        cq = rms_norm(cq, p["norm_q"])
+        cq = rms_norm(cq, p["norm_q"], eps)
     else:
         cq = x
     q_nope = jnp.einsum("bsr,rhd->bshd", cq, p["w_uq"].astype(dt),
@@ -304,13 +305,14 @@ def _mla_q(p: dict[str, Any], x: jax.Array) -> tuple[jax.Array, jax.Array]:
     return q_nope, q_rope
 
 
-def _mla_latent(p: dict[str, Any], x: jax.Array) -> tuple[jax.Array, jax.Array]:
+def _mla_latent(p: dict[str, Any], x: jax.Array,
+                eps: float = 1e-5) -> tuple[jax.Array, jax.Array]:
     from repro.models.layers import rms_norm
 
     dt = x.dtype
     c_kv = jnp.einsum("bsd,dr->bsr", x, p["w_dkv"].astype(dt),
                       preferred_element_type=jnp.float32).astype(dt)
-    c_kv = rms_norm(c_kv, p["norm_kv"])
+    c_kv = rms_norm(c_kv, p["norm_kv"], eps)
     k_rope = jnp.einsum("bsd,dr->bsr", x, p["w_kr"].astype(dt),
                         preferred_element_type=jnp.float32).astype(dt)
     return c_kv, k_rope
@@ -324,14 +326,18 @@ def mla_prefill(
     *,
     kv_chunk: int = 1024,
     probs_bf16: bool = False,
+    scale: float | None = None,
+    eps: float = 1e-5,
 ) -> tuple[jax.Array, tuple[jax.Array, jax.Array]]:
-    """Materialized-KV MLA for train/prefill; caches (c_kv, k_rope) only."""
+    """Materialized-KV MLA for train/prefill; caches (c_kv, k_rope) only.
+    ``scale`` is the softmax scale, ``(dn + dr)^-½`` where None; ``eps``
+    the latent norms' epsilon."""
     dt = x.dtype
     B, S, D = x.shape
-    q_nope, q_rope = _mla_q(p, x)
+    q_nope, q_rope = _mla_q(p, x, eps)
     dn = q_nope.shape[-1]
     dr = q_rope.shape[-1]
-    c_kv, k_rope = _mla_latent(p, x)
+    c_kv, k_rope = _mla_latent(p, x, eps)
     q_rope = apply_rope(q_rope, cos, sin)
     k_rope = apply_rope(k_rope[:, :, None, :], cos, sin)[:, :, 0, :]
     # materialize per-head keys/values from the latent (train/prefill path)
@@ -345,7 +351,7 @@ def mla_prefill(
     k = jnp.concatenate(
         [k_nope, jnp.broadcast_to(k_rope[:, :, None, :], (B, S, H, dr))], axis=-1
     )
-    scale = (dn + dr) ** -0.5
+    scale = (dn + dr) ** -0.5 if scale is None else scale
     out = flash_attention(q, k, v, causal=True, kv_chunk=kv_chunk, scale=scale,
                           probs_bf16=probs_bf16)
     y = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt),
@@ -353,45 +359,53 @@ def mla_prefill(
     return y, (c_kv, k_rope)
 
 
-def mla_decode(
+def mla_decode_inputs(
     p: dict[str, Any],
     x: jax.Array,             # (B, 1, D)
-    ckv_cache: jax.Array,     # (B, S_max, r)
-    krope_cache: jax.Array,   # (B, S_max, dr)
-    pos: jax.Array,           # (B,)
     cos: jax.Array,
     sin: jax.Array,
-) -> tuple[jax.Array, tuple[jax.Array, jax.Array]]:
-    """Absorbed-form decode: attention runs entirely in the latent space.
-
-    scores = q_nope·W_uk ⊙ c_kv  +  q_rope·k_rope   — cache stays (S, r + dr).
-    """
+    eps: float = 1e-5,
+) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """The new token's part of absorbed-form decode: the latent-space query
+    ``q_nope · W_uk`` (B, H, r) in float32, the roped query (B, H, dr), and
+    the cache rows it adds, ``c_kv`` (B, r) and roped ``k_rope`` (B, dr)."""
     dt = x.dtype
-    B = x.shape[0]
-    S = ckv_cache.shape[1]
-    q_nope, q_rope = _mla_q(p, x)                       # (B,1,H,dn/dr)
+    q_nope, q_rope = _mla_q(p, x, eps)                  # (B,1,H,dn/dr)
     q_rope = apply_rope(q_rope, cos, sin)
-    c_kv, k_rope = _mla_latent(p, x)                    # (B,1,r), (B,1,dr)
+    c_kv, k_rope = _mla_latent(p, x, eps)               # (B,1,r), (B,1,dr)
     k_rope = apply_rope(k_rope[:, :, None, :], cos, sin)[:, :, 0, :]
-
-    onehot = (jnp.arange(S)[None, :] == pos[:, None]).astype(ckv_cache.dtype)
-    ckv_cache = ckv_cache * (1 - onehot)[..., None] + c_kv * onehot[..., None]
-    krope_cache = krope_cache * (1 - onehot)[..., None] + k_rope * onehot[..., None]
-
-    dn = q_nope.shape[-1]
-    dr = q_rope.shape[-1]
-    scale = (dn + dr) ** -0.5
-    # absorb W_uk into the query → latent-space query (B, H, r)
     q_lat = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0], p["w_uk"].astype(dt),
                        preferred_element_type=jnp.float32)
-    s = jnp.einsum("bhr,bsr->bhs", q_lat, ckv_cache.astype(jnp.float32))
-    s = s + jnp.einsum("bhd,bsd->bhs", q_rope[:, 0].astype(jnp.float32),
-                       krope_cache.astype(jnp.float32))
+    return q_lat, q_rope[:, 0], c_kv[:, 0], k_rope[:, 0]
+
+
+def mla_decode_attend(
+    p: dict[str, Any],
+    q_lat: jax.Array,         # (B, H, r) float32
+    q_rope: jax.Array,        # (B, H, dr)
+    ckv_cache: jax.Array,     # (B, S_max, r), the new rows written
+    krope_cache: jax.Array,   # (B, S_max, dr)
+    pos: jax.Array,           # (B,)
+    scale: float,
+    dt,
+) -> jax.Array:
+    """Attention in the latent space over cache rows ``0..pos``:
+    scores = q_lat · c_kv + q_rope · k_rope; out = softmax · c_kv · W_uv · W_o.
+    Operands in ``dt``, sums and the softmax in float32.  Returns (B, 1, D)."""
+    S = ckv_cache.shape[1]
+    f32 = jnp.float32
+    s = jnp.einsum("bhr,bsr->bhs", q_lat.astype(dt), ckv_cache.astype(dt),
+                   preferred_element_type=f32)
+    s = s + jnp.einsum("bhd,bsd->bhs", q_rope.astype(dt),
+                       krope_cache.astype(dt), preferred_element_type=f32)
     s = s * scale
     mask = jnp.arange(S)[None, :] <= pos[:, None]
     s = jnp.where(mask[:, None, :], s, _NEG)
     pr = jax.nn.softmax(s, axis=-1)
-    ctx_lat = jnp.einsum("bhs,bsr->bhr", pr, ckv_cache.astype(jnp.float32))
-    ctx = jnp.einsum("bhr,rhd->bhd", ctx_lat, p["w_uv"].astype(jnp.float32))
-    y = jnp.einsum("bhk,hkd->bd", ctx, p["wo"].astype(jnp.float32))
-    return y[:, None, :].astype(dt), (ckv_cache, krope_cache)
+    ctx_lat = jnp.einsum("bhs,bsr->bhr", pr.astype(dt), ckv_cache.astype(dt),
+                         preferred_element_type=f32)
+    ctx = jnp.einsum("bhr,rhd->bhd", ctx_lat.astype(dt), p["w_uv"].astype(dt),
+                     preferred_element_type=f32)
+    y = jnp.einsum("bhk,hkd->bd", ctx.astype(dt), p["wo"].astype(dt),
+                   preferred_element_type=f32)
+    return y[:, None, :].astype(dt)

@@ -15,6 +15,7 @@ tensor layouts stable and shard-friendly (heads-last attention weights,
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any
 
 import jax
@@ -23,7 +24,8 @@ import numpy as np
 
 __all__ = [
     "Initializer", "he_init", "rms_norm", "init_linear", "linear",
-    "init_mlp", "mlp_swiglu", "rope_table", "apply_rope",
+    "init_mlp", "mlp_swiglu", "YaRN", "rope_freqs", "rope_table",
+    "apply_rope",
     "cross_entropy_loss", "pad_vocab", "ACT_DTYPE",
 ]
 
@@ -104,14 +106,79 @@ def mlp_swiglu(p: dict[str, Any], x: jax.Array) -> jax.Array:
 
 
 # ----------------------------------------------------------------------- RoPE
-def rope_table(seq_len: int, dim: int, theta: float = 1e4,
-               offset: int = 0) -> tuple[jax.Array, jax.Array]:
-    """(seq_len, dim/2) cos/sin tables starting at absolute position ``offset``."""
+@dataclasses.dataclass(frozen=True)
+class YaRN:
+    """YaRN context extension of RoPE, as DeepSeek-V2 configures it
+    (``rope_scaling`` with ``type: yarn``): frequencies blended between
+    interpolated (÷ ``factor``) and original by a linear ramp over the
+    dimensions whose wavelengths lie between ``beta_fast`` and ``beta_slow``
+    rotations of ``original_max_position``; cos/sin scaled by
+    ``mscale(factor, mscale) / mscale(factor, mscale_all_dim)``, and the
+    attention's softmax scale by ``mscale(factor, mscale_all_dim)²``."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    @staticmethod
+    def get_mscale(factor: float, m: float) -> float:
+        return 1.0 if factor <= 1.0 else 0.1 * m * math.log(factor) + 1.0
+
+    @property
+    def cos_sin_scale(self) -> float:
+        return (self.get_mscale(self.factor, self.mscale)
+                / self.get_mscale(self.factor, self.mscale_all_dim))
+
+    @property
+    def softmax_factor(self) -> float:
+        if not self.mscale_all_dim:
+            return 1.0
+        return self.get_mscale(self.factor, self.mscale_all_dim) ** 2
+
+    def _correction_dim(self, rotations: float, dim: int, theta: float):
+        return (dim * math.log(self.original_max_position
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    def freqs(self, dim: int, theta: float) -> np.ndarray:
+        half = dim // 2
+        extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+        inter = extra / self.factor
+        low = max(math.floor(self._correction_dim(self.beta_fast, dim,
+                                                  theta)), 0)
+        high = min(math.ceil(self._correction_dim(self.beta_slow, dim,
+                                                  theta)), dim - 1)
+        if low == high:
+            high += 0.001
+        ramp = np.clip((np.arange(half) - low) / (high - low), 0.0, 1.0)
+        keep = 1.0 - ramp                  # 1: original frequency
+        return (inter * (1.0 - keep) + extra * keep).astype(np.float32)
+
+
+def rope_freqs(dim: int, theta: float = 1e4,
+               scaling: YaRN | None = None) -> jax.Array:
+    """(dim/2,) inverse frequencies, YaRN-blended where ``scaling``."""
+    if scaling is not None:
+        return jnp.asarray(scaling.freqs(dim, theta))
     half = dim // 2
-    freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    return 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+
+
+def rope_table(seq_len: int, dim: int, theta: float = 1e4,
+               offset: int = 0, scaling: YaRN | None = None
+               ) -> tuple[jax.Array, jax.Array]:
+    """(seq_len, dim/2) cos/sin tables starting at absolute position
+    ``offset`` (scaled by YaRN's cos/sin factor where ``scaling``)."""
+    freqs = rope_freqs(dim, theta, scaling)
     pos = jnp.arange(offset, offset + seq_len, dtype=jnp.float32)
     ang = pos[:, None] * freqs[None, :]
-    return jnp.cos(ang), jnp.sin(ang)
+    if scaling is None:
+        return jnp.cos(ang), jnp.sin(ang)
+    m = scaling.cos_sin_scale
+    return jnp.cos(ang) * m, jnp.sin(ang) * m
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:

@@ -1,7 +1,9 @@
-"""Mixture-of-Experts FFN with top-k routing and capacity dispatch.
+"""Mixture-of-Experts FFN with top-k routing: capacity dispatch for training,
+dropless grouped dispatch for serving.
 
 Used by olmoe (64 experts, top-8) and deepseek-v2 (2 shared + 160 routed,
-top-6).  Design goals, in order:
+top-6; V2-Lite 2 shared + 64 routed, top-6).  The capacity path
+(:func:`moe_ffn`), in order of its design goals:
 
 1.  **Linear in tokens.**  The dispatch is slot-scatter / slot-gather:
     every (token, choice) pair gets a slot ``expert·cap + position`` computed
@@ -14,6 +16,11 @@ top-6).  Design goals, in order:
     induces the expected all-to-all under GSPMD.
 
 3.  **Load-balance aux loss** (Switch/GShard form): ``E · Σ_e f_e · p_e``.
+
+The serving path (:func:`moe_dropless`) loses no token: the (token, choice)
+pairs are sorted by expert and each expert's SwiGLU runs over exactly the
+rows it received (``jax.lax.ragged_dot``, a grouped matmul), so a batch that
+routes unevenly costs time and never gate mass.
 """
 
 from __future__ import annotations
@@ -23,10 +30,10 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import Initializer, he_init
+from repro.models.layers import Initializer, he_init, mlp_swiglu
 from repro.sharding.ctx import shard_act
 
-__all__ = ["init_moe", "moe_ffn"]
+__all__ = ["init_moe", "moe_ffn", "moe_dropless", "expert_counts"]
 
 
 def init_moe(
@@ -55,12 +62,47 @@ def init_moe(
     return p
 
 
+def _route(p: dict[str, Any], xt: jax.Array, k: int, norm_topk_prob: bool,
+           routed_scaling_factor: float):
+    """Softmax router in float32 at full precision, greedy top-``k``:
+    (gates (T, E), chosen gates (T, k), chosen experts (T, k)).  The chosen
+    gates are renormalised to sum to one only where ``norm_topk_prob``, then
+    scaled by ``routed_scaling_factor``."""
+    logits = jnp.einsum("td,de->te", xt.astype(jnp.float32), p["router"],
+                        precision=jax.lax.Precision.HIGHEST)
+    gates = jax.nn.softmax(logits, axis=-1)
+    top_g, top_i = jax.lax.top_k(gates, k)
+    if norm_topk_prob:
+        top_g = top_g / jnp.maximum(top_g.sum(-1, keepdims=True), 1e-9)
+    return gates, top_g * routed_scaling_factor, top_i
+
+
+def _count(top_i: jax.Array, n_experts: int,
+           mask: jax.Array | None) -> jax.Array:
+    """Routed choices per expert, over the tokens ``mask`` keeps."""
+    w = (jnp.ones(top_i.shape, jnp.int32) if mask is None else
+         jnp.broadcast_to(mask.reshape(-1, 1), top_i.shape).astype(jnp.int32))
+    return jnp.zeros((n_experts,), jnp.int32).at[top_i.reshape(-1)].add(
+        w.reshape(-1))
+
+
+def expert_counts(p: dict[str, Any], x: jax.Array, *, k: int,
+                  mask: jax.Array | None = None) -> jax.Array:
+    """(E,) choices the router makes for the tokens of ``x`` (B, S, D) that
+    ``mask`` (B, S) keeps: the expert load of the capacity path."""
+    xt = x.reshape(-1, x.shape[-1])
+    _, _, top_i = _route(p, xt, k, False, 1.0)
+    return _count(top_i, p["router"].shape[-1], mask)
+
+
 def moe_ffn(
     p: dict[str, Any],
     x: jax.Array,               # (B, S, D)
     *,
     k: int,
     capacity_factor: float = 1.25,
+    norm_topk_prob: bool = True,
+    routed_scaling_factor: float = 1.0,
 ) -> tuple[jax.Array, jax.Array]:
     """Returns (output (B,S,D), aux load-balance loss scalar)."""
     B, S, D = x.shape
@@ -72,10 +114,8 @@ def moe_ffn(
     dt = x.dtype
 
     xt = x.reshape(T, D)
-    logits = jnp.einsum("td,de->te", xt.astype(jnp.float32), p["router"])
-    gates = jax.nn.softmax(logits, axis=-1)                     # (T, E)
-    top_g, top_i = jax.lax.top_k(gates, k)                      # (T, k)
-    top_g = top_g / jnp.maximum(top_g.sum(-1, keepdims=True), 1e-9)
+    gates, top_g, top_i = _route(p, xt, k, norm_topk_prob,
+                                 routed_scaling_factor)
 
     # ---- slot assignment: running count per expert, slot-priority order
     counts = jnp.zeros((E,), jnp.int32)
@@ -123,8 +163,6 @@ def moe_ffn(
 
     # ---- shared experts (deepseek): always-on dense SwiGLU
     if "shared" in p:
-        from repro.models.layers import mlp_swiglu
-
         out = out + mlp_swiglu(p["shared"], xt)
 
     # ---- aux loss: fraction dispatched (1st choice) × mean router prob
@@ -132,3 +170,45 @@ def moe_ffn(
     pr = jnp.mean(gates, axis=0)
     aux = E * jnp.sum(f * pr)
     return out.reshape(B, S, D), aux
+
+
+def moe_dropless(
+    p: dict[str, Any],
+    x: jax.Array,               # (B, S, D)
+    *,
+    k: int,
+    norm_topk_prob: bool = False,
+    routed_scaling_factor: float = 1.0,
+    count_mask: jax.Array | None = None,   # (B, S): tokens to count
+) -> tuple[jax.Array, jax.Array]:
+    """Dropless MoE: every token's ``k`` chosen experts, and only those.
+
+    The T·k (token, choice) rows are sorted by expert; a grouped matmul
+    (``ragged_dot``) runs each expert's SwiGLU over its own contiguous rows;
+    the rows are put back in token order, weighted by their gates in float32
+    and summed; the shared experts are added.  No capacity, no token dropped.
+    Returns (output (B, S, D), routed choices per expert (E,) over the
+    tokens ``count_mask`` keeps)."""
+    B, S, D = x.shape
+    E = p["router"].shape[-1]
+    T = B * S
+    dt = x.dtype
+    xt = x.reshape(T, D)
+    _, top_g, top_i = _route(p, xt, k, norm_topk_prob, routed_scaling_factor)
+    flat_e = top_i.reshape(-1)                                  # (T·k,)
+    order = jnp.argsort(flat_e, stable=True)
+    rows = xt[order // k]                                       # sorted rows
+    sizes = jnp.zeros((E,), jnp.int32).at[flat_e].add(1)
+    g = jax.lax.ragged_dot(rows, p["w_gate"].astype(dt), sizes,
+                           preferred_element_type=jnp.float32)
+    u = jax.lax.ragged_dot(rows, p["w_up"].astype(dt), sizes,
+                           preferred_element_type=jnp.float32)
+    h = (jax.nn.silu(g) * u).astype(dt)
+    y = jax.lax.ragged_dot(h, p["w_down"].astype(dt), sizes,
+                           preferred_element_type=jnp.float32)  # (T·k, D)
+    back = jnp.argsort(order)                                   # unsort
+    y = y[back].reshape(T, k, D)
+    out = jnp.sum(y * top_g[..., None], axis=1)     # elementwise: float32
+    if "shared" in p:
+        out = out + mlp_swiglu(p["shared"], xt).astype(jnp.float32)
+    return out.astype(dt).reshape(B, S, D), _count(top_i, E, count_mask)

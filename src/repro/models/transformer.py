@@ -11,7 +11,9 @@ One parameter tree + three entry points:
 Families (``ModelConfig.family``):
   dense   — pre-norm GQA transformer (granite, command-r, codeqwen, qwen2.5,
             musicgen backbone, internvl2 backbone)
-  moe     — GQA or MLA attention + top-k routed experts (olmoe, deepseek-v2)
+  moe     — GQA or MLA attention + top-k routed experts (olmoe, deepseek-v2);
+            ``n_dense_layers`` leading layers keep a dense SwiGLU of
+            ``d_ff`` (deepseek-v2-lite's ``first_k_dense_replace``)
   ssm     — attention-free Mamba2 SSD stack (mamba2-1.3b)
   hybrid  — Mamba2 backbone with a *shared* attention block applied every
             ``hybrid_attn_every`` layers (zamba2-7b); the shared block runs at
@@ -19,7 +21,9 @@ Families (``ModelConfig.family``):
 
 Layers are stacked (leading L axis) and driven by ``lax.scan`` so the lowered
 HLO stays compact for the 512-device dry-run; each block is wrapped in
-``jax.checkpoint`` (nothing saveable) when ``cfg.remat``.
+``jax.checkpoint`` (nothing saveable) when ``cfg.remat``.  A moe model with
+leading dense layers has two stacks, ``dense_blocks`` then ``blocks``, each
+its own scan; its cache leaves keep one layer axis over both.
 """
 
 from __future__ import annotations
@@ -36,12 +40,14 @@ from repro.models import mamba2 as m2
 from repro.models import moe as moe_mod
 from repro.models.layers import (
     Initializer,
+    YaRN,
     cross_entropy_loss,
     he_init,
     init_mlp,
     mlp_swiglu,
     pad_vocab,
     rms_norm,
+    rope_freqs,
     rope_table,
 )
 from repro.sharding.ctx import shard_act
@@ -68,6 +74,10 @@ class ModelConfig:
     d_ff_expert: int = 0
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
+    n_dense_layers: int = 0         # leading layers with a dense FFN (d_ff)
+    norm_topk_prob: bool = True     # renormalise the chosen gates to sum 1
+    routed_scaling_factor: float = 1.0
+    moe_impl: str = "capacity"      # capacity (training) | dropless (serving)
     # --- MLA (deepseek-v2)
     use_mla: bool = False
     kv_lora_rank: int = 0
@@ -95,6 +105,7 @@ class ModelConfig:
     attn_probs_bf16: bool = False
     norm_eps: float = 1e-5
     rope_theta: float = 1e4
+    rope_scaling: YaRN | None = None
     modality: str = "text"          # text | audio_tokens | vision_prefix
     vision_prefix_len: int = 0
     act_dtype: str = "bfloat16"
@@ -116,6 +127,21 @@ class ModelConfig:
     @property
     def pdt(self):
         return jnp.dtype(self.param_dtype)
+
+    @property
+    def n_moe_layers(self) -> int:
+        return self.n_layers - self.n_dense_layers if self.family == "moe" else 0
+
+    @property
+    def rope_dim(self) -> int:
+        return self.d_rope if self.use_mla else self.d_head
+
+    @property
+    def mla_scale(self) -> float:
+        """MLA's softmax scale: (qk_nope + qk_rope)^-½, times YaRN's
+        mscale(factor, mscale_all_dim)²."""
+        f = 1.0 if self.rope_scaling is None else self.rope_scaling.softmax_factor
+        return (self.d_head + self.d_rope) ** -0.5 * f
 
     @property
     def d_inner(self) -> int:
@@ -180,7 +206,8 @@ def _stack(fn, n: int):
     return stacked
 
 
-def _init_attn_block(cfg: ModelConfig, ini: Initializer) -> dict[str, Any]:
+def _init_attn_block(cfg: ModelConfig, ini: Initializer,
+                     moe: bool = False) -> dict[str, Any]:
     dt = cfg.pdt
     blk: dict[str, Any] = {"norm1": jnp.ones((cfg.d_model,), dt),
                            "norm2": jnp.ones((cfg.d_model,), dt)}
@@ -199,7 +226,7 @@ def _init_attn_block(cfg: ModelConfig, ini: Initializer) -> dict[str, Any]:
             # zero the padded heads' output rows → identical function at init
             wo = blk["attn"]["wo"]
             blk["attn"]["wo"] = wo.at[cfg.n_heads:].set(0.0)
-    if cfg.family == "moe":
+    if moe:
         blk["moe"] = moe_mod.init_moe(
             ini, cfg.d_model, cfg.d_ff_expert, cfg.n_experts,
             n_shared=cfg.n_shared_experts, dtype=dt,
@@ -243,10 +270,18 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict[str, Any]:
         "final_norm": jnp.ones((D,), dt),
         "lm_head": he_init(ini, (D, Vp), D, dt),
     }
-    if cfg.family in ("dense", "moe"):
+    if cfg.family == "dense":
         params["blocks"] = _stack(
             functools.partial(_init_attn_block, cfg), cfg.n_layers
         )(ini)
+    elif cfg.family == "moe":
+        if cfg.n_dense_layers:
+            params["dense_blocks"] = _stack(
+                functools.partial(_init_attn_block, cfg), cfg.n_dense_layers
+            )(ini)
+        params["blocks"] = _stack(
+            functools.partial(_init_attn_block, cfg, moe=True),
+            cfg.n_moe_layers)(ini)
     elif cfg.family == "ssm":
         params["blocks"] = _stack(
             functools.partial(_init_mamba_block, cfg), cfg.n_layers
@@ -292,23 +327,42 @@ def _maybe_remat(fn, cfg: ModelConfig):
     return fn
 
 
-def _attn_block_full(cfg: ModelConfig, blk, x, cos, sin, window):
+def _ffn(cfg: ModelConfig, blk, h, count_mask):
+    """The block's FFN: (output, aux loss, routed choices per expert or
+    None).  Dense blocks have no experts."""
+    if "moe" not in blk:
+        return mlp_swiglu(blk["mlp"], h), jnp.zeros((), jnp.float32), None
+    k = cfg.experts_per_token
+    if cfg.moe_impl == "dropless":
+        f, counts = moe_mod.moe_dropless(
+            blk["moe"], h, k=k, norm_topk_prob=cfg.norm_topk_prob,
+            routed_scaling_factor=cfg.routed_scaling_factor,
+            count_mask=count_mask)
+        return f, jnp.zeros((), jnp.float32), counts
+    f, aux = moe_mod.moe_ffn(blk["moe"], h, k=k,
+                             capacity_factor=cfg.capacity_factor,
+                             norm_topk_prob=cfg.norm_topk_prob,
+                             routed_scaling_factor=cfg.routed_scaling_factor)
+    counts = (None if count_mask is None else
+              moe_mod.expert_counts(blk["moe"], h, k=k, mask=count_mask))
+    return f, aux, counts
+
+
+def _attn_block_full(cfg: ModelConfig, blk, x, cos, sin, window,
+                     count_mask=None):
     h = rms_norm(x, blk["norm1"], cfg.norm_eps)
     if cfg.use_mla:
         a, cache = attn.mla_prefill(blk["attn"], h, cos, sin, kv_chunk=cfg.kv_chunk,
-                                    probs_bf16=cfg.attn_probs_bf16)
+                                    probs_bf16=cfg.attn_probs_bf16,
+                                    scale=cfg.mla_scale, eps=cfg.norm_eps)
     else:
         a, cache = attn.gqa_prefill(blk["attn"], h, cos, sin, window=window,
                                     kv_chunk=cfg.kv_chunk,
                                     probs_bf16=cfg.attn_probs_bf16)
     x = x + a
     h = rms_norm(x, blk["norm2"], cfg.norm_eps)
-    if cfg.family == "moe":
-        f, aux = moe_mod.moe_ffn(blk["moe"], h, k=cfg.experts_per_token,
-                                 capacity_factor=cfg.capacity_factor)
-    else:
-        f, aux = mlp_swiglu(blk["mlp"], h), jnp.zeros((), jnp.float32)
-    return x + f, cache, aux
+    f, aux, counts = _ffn(cfg, blk, h, count_mask)
+    return x + f, cache, aux, counts
 
 
 def _shared_block_full(cfg: ModelConfig, sp, x, emb0, cos2, sin2, window):
@@ -331,6 +385,20 @@ def _embed(cfg: ModelConfig, params, tokens, prefix_embeds):
     return emb
 
 
+def _stacks(params) -> list:
+    """The attention families' layer stacks in order: the leading dense
+    layers (moe models that have them), then the rest."""
+    return [params[k] for k in ("dense_blocks", "blocks") if k in params]
+
+
+def _stack_len(blocks) -> int:
+    return jax.tree.leaves(blocks)[0].shape[0]
+
+
+def _cat_layers(parts: list):
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
+
+
 def forward_full(
     params: dict[str, Any],
     cfg: ModelConfig,
@@ -339,30 +407,48 @@ def forward_full(
     prefix_embeds: jax.Array | None = None,  # (B, Np, D) — vision stub
     window: int | None = None,
     return_cache: bool = False,
-) -> tuple[jax.Array, Any, jax.Array]:
-    """Teacher-forced forward.  Returns (logits (B,S,Vp), caches|None, aux)."""
+    head_positions: jax.Array | None = None,  # (B,) int32
+    count_mask: jax.Array | None = None,      # (B, S) bool
+) -> tuple:
+    """Teacher-forced forward.  Returns (logits (B,S,Vp), caches|None, aux).
+
+    ``head_positions`` computes the norm and the LM head at one position
+    per row only, giving logits (B, Vp).  Where ``count_mask`` is given a
+    fourth element follows: the routed choices per expert of the tokens it
+    keeps, (MoE layers, E) int32 (None for a model with no experts)."""
     window = cfg.attn_window if window is None else window
     x = shard_act(_embed(cfg, params, tokens, prefix_embeds), "hidden")
     B, S, D = x.shape
     aux_total = jnp.zeros((), jnp.float32)
-    caches = None
+    caches = counts = None
 
     if cfg.family in ("dense", "moe"):
-        cos, sin = rope_table(S, cfg.d_rope if cfg.use_mla else cfg.d_head,
-                              cfg.rope_theta)
+        cos, sin = rope_table(S, cfg.rope_dim, cfg.rope_theta,
+                              scaling=cfg.rope_scaling)
 
         def body(carry, blk):
             h, aux = carry
-            h2, cache, a = _maybe_remat(
-                lambda b, hh: _attn_block_full(cfg, b, hh, cos, sin, window), cfg
+            h2, cache, a, c = _maybe_remat(
+                lambda b, hh: _attn_block_full(cfg, b, hh, cos, sin, window,
+                                               count_mask), cfg
             )(blk, h)
             out = cache if return_cache else None
-            return (h2, aux + a), out
+            return (h2, aux + a), (out, c)
 
-        (x, aux_total), caches = jax.lax.scan(body, (x, aux_total), params["blocks"])
+        cache_parts, count_parts = [], []
+        for blocks in _stacks(params):
+            (x, aux_total), (cache, c) = jax.lax.scan(body, (x, aux_total),
+                                                      blocks)
+            cache_parts.append(cache)
+            if c is not None:
+                count_parts.append(c)
+        if count_parts:
+            counts = _cat_layers(count_parts)
         if return_cache:
-            caches = {"k": caches[0], "v": caches[1]} if not cfg.use_mla else {
-                "ckv": caches[0], "kr": caches[1]}
+            c0 = _cat_layers([c[0] for c in cache_parts])
+            c1 = _cat_layers([c[1] for c in cache_parts])
+            caches = ({"ckv": c0, "kr": c1} if cfg.use_mla
+                      else {"k": c0, "v": c1})
 
     elif cfg.family == "ssm":
         def body(h, blk):
@@ -382,10 +468,18 @@ def forward_full(
     else:
         raise ValueError(cfg.family)
 
-    x = rms_norm(shard_act(x, "hidden"), params["final_norm"], cfg.norm_eps)
+    x = shard_act(x, "hidden")
+    if head_positions is not None:
+        x = x[jnp.arange(B), head_positions][:, None, :]
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"].astype(cfg.adt),
                         preferred_element_type=jnp.float32)
-    return shard_act(logits, "logits"), caches, aux_total
+    logits = shard_act(logits, "logits")
+    if head_positions is not None:
+        logits = logits[:, 0]
+    if count_mask is not None:
+        return logits, caches, aux_total, counts
+    return logits, caches, aux_total
 
 
 def _hybrid_full(params, cfg: ModelConfig, x, window, return_cache):
@@ -432,11 +526,14 @@ def _hybrid_full(params, cfg: ModelConfig, x, window, return_cache):
 
 
 # =================================================================== decode
-def _rope_at(pos: jax.Array, dim: int, theta: float) -> tuple[jax.Array, jax.Array]:
-    half = dim // 2
-    freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+def _rope_at(pos: jax.Array, dim: int, theta: float,
+             scaling: YaRN | None = None) -> tuple[jax.Array, jax.Array]:
+    freqs = rope_freqs(dim, theta, scaling)
     ang = pos.astype(jnp.float32)[:, None] * freqs[None, :]
-    return jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]   # (B,1,half)
+    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]  # (B,1,half)
+    if scaling is not None:
+        cos, sin = cos * scaling.cos_sin_scale, sin * scaling.cos_sin_scale
+    return cos, sin
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int, *, abstract: bool = False):
@@ -477,40 +574,89 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, *, abstract: bool = F
     raise ValueError(cfg.family)
 
 
+def _mla_decode_stacks(params, cfg: ModelConfig, x, caches, pos, cos, sin,
+                       mask):
+    """MLA decode over the layer stacks with the latent cache carried
+    whole through each scan: the new token's rows are scattered into layer
+    ``i`` in place and attention reads that layer's rows, so a donated
+    cache is updated where it lies and never copied."""
+    b = jnp.arange(x.shape[0])
+    ckv, kr = caches["ckv"], caches["kr"]
+
+    def body(carry, blk):
+        h, ckv, kr, i = carry
+        hn = rms_norm(h, blk["norm1"], cfg.norm_eps)
+        q_lat, q_rope, c_new, k_new = attn.mla_decode_inputs(
+            blk["attn"], hn, cos, sin, cfg.norm_eps)
+        ckv = ckv.at[i, b, pos].set(c_new.astype(ckv.dtype))
+        kr = kr.at[i, b, pos].set(k_new.astype(kr.dtype))
+        a = attn.mla_decode_attend(blk["attn"], q_lat, q_rope, ckv[i], kr[i],
+                                   pos, cfg.mla_scale, h.dtype)
+        h = h + a
+        f, _, c = _ffn(cfg, blk, rms_norm(h, blk["norm2"], cfg.norm_eps),
+                       mask)
+        return (h + f, ckv, kr, i + 1), c
+
+    counts, layer = [], jnp.int32(0)
+    for blocks in _stacks(params):
+        (x, ckv, kr, layer), c = jax.lax.scan(body, (x, ckv, kr, layer),
+                                              blocks)
+        if c is not None:
+            counts.append(c)
+    return x, {"ckv": ckv, "kr": kr}, counts
+
+
+def _gqa_decode_stacks(params, cfg: ModelConfig, x, caches, pos, cos, sin,
+                       mask):
+    def body(h, xs):
+        blk, c0, c1 = xs
+        hn = rms_norm(h, blk["norm1"], cfg.norm_eps)
+        a, (c0, c1) = attn.gqa_decode(blk["attn"], hn, c0, c1, pos, cos, sin,
+                                      window=cfg.attn_window)
+        h = h + a
+        f, _, c = _ffn(cfg, blk, rms_norm(h, blk["norm2"], cfg.norm_eps),
+                       mask)
+        return h + f, (c0, c1, c)
+
+    stacks = _stacks(params)
+    counts, new_k, new_v, layer = [], [], [], 0
+    for blocks in stacks:
+        n = _stack_len(blocks)
+        k, v = caches["k"], caches["v"]
+        if len(stacks) > 1:
+            k, v = k[layer:layer + n], v[layer:layer + n]
+        x, (k, v, c) = jax.lax.scan(body, x, (blocks, k, v))
+        new_k.append(k)
+        new_v.append(v)
+        if c is not None:
+            counts.append(c)
+        layer += n
+    return x, {"k": _cat_layers(new_k), "v": _cat_layers(new_v)}, counts
+
+
 def forward_decode(
     params: dict[str, Any],
     cfg: ModelConfig,
     token: jax.Array,        # (B,) int32 — the newest token
     caches: Any,
     pos: jax.Array,          # (B,) int32 — its position (current length)
-) -> tuple[jax.Array, Any]:
-    """One decode step; returns (logits (B, Vp), updated caches)."""
+    *,
+    count_mask: jax.Array | None = None,   # (B,) bool
+) -> tuple:
+    """One decode step; returns (logits (B, Vp), updated caches), and where
+    ``count_mask`` is given a third element: the routed choices per expert
+    of the rows it keeps, (MoE layers, E) int32, or None."""
     x = jnp.take(params["embed"].astype(cfg.adt), token[:, None], axis=0)
+    counts = None
 
     if cfg.family in ("dense", "moe"):
-        cos, sin = _rope_at(pos, cfg.d_rope if cfg.use_mla else cfg.d_head,
-                            cfg.rope_theta)
-
-        def body(h, xs):
-            blk, c0, c1 = xs
-            hn = rms_norm(h, blk["norm1"], cfg.norm_eps)
-            if cfg.use_mla:
-                a, (c0, c1) = attn.mla_decode(blk["attn"], hn, c0, c1, pos, cos, sin)
-            else:
-                a, (c0, c1) = attn.gqa_decode(blk["attn"], hn, c0, c1, pos, cos, sin,
-                                              window=cfg.attn_window)
-            h = h + a
-            hn = rms_norm(h, blk["norm2"], cfg.norm_eps)
-            if cfg.family == "moe":
-                f, _ = moe_mod.moe_ffn(blk["moe"], hn, k=cfg.experts_per_token,
-                                       capacity_factor=cfg.capacity_factor)
-            else:
-                f = mlp_swiglu(blk["mlp"], hn)
-            return h + f, (c0, c1)
-
-        keys = ("ckv", "kr") if cfg.use_mla else ("k", "v")
-        x, new = jax.lax.scan(body, x, (params["blocks"], caches[keys[0]], caches[keys[1]]))
-        caches = {keys[0]: new[0], keys[1]: new[1]}
+        cos, sin = _rope_at(pos, cfg.rope_dim, cfg.rope_theta,
+                            cfg.rope_scaling)
+        mask = None if count_mask is None else count_mask[:, None]
+        stacks = _mla_decode_stacks if cfg.use_mla else _gqa_decode_stacks
+        x, caches, parts = stacks(params, cfg, x, caches, pos, cos, sin, mask)
+        if parts:
+            counts = _cat_layers(parts)
 
     elif cfg.family == "ssm":
         def body(h, xs):
@@ -533,7 +679,10 @@ def forward_decode(
     x = rms_norm(shard_act(x, "hidden"), params["final_norm"], cfg.norm_eps)
     logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"].astype(cfg.adt),
                         preferred_element_type=jnp.float32)
-    return shard_act(logits, "logits")[:, 0], caches
+    logits = shard_act(logits, "logits")[:, 0]
+    if count_mask is not None:
+        return logits, caches, counts
+    return logits, caches
 
 
 def _hybrid_decode(params, cfg: ModelConfig, x, caches, pos):

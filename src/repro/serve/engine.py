@@ -9,8 +9,21 @@ inserted into a free slot; ``step()`` then decodes one token for *every*
 active slot in a single batched ``forward_decode`` — the batching the
 decode_32k shape cell measures.
 
-All device work happens in two jit'd functions (`_prefill`, `_decode`);
-the Python layer only does slot bookkeeping.
+All device work happens in three jit'd programs: ``lm_prefill`` (one
+prompt; the LM head at its last position only), ``lm_insert`` (the
+prompt's cache rows written into its slot) and ``lm_decode`` (every slot one
+token; greedy tokens picked on the device).  The insert and, unsharded, the
+decode donate the cache, so it is updated where it lies and exists once.
+The Python layer only does slot bookkeeping.
+
+Spans (``repro.core.tracing``, ``mafia.lm.*``) mark the prefill, the insert
+and each decode step with its dispatch, fetch and sampling; the same clock
+readings are kept as records (``prefill_records``, ``decode_records``,
+rows of :data:`PREFILL_FIELDS` / :data:`DECODE_FIELDS`), beside the
+counters (``counters``) and the routed choices per expert and MoE layer
+(``expert_counts``), which the jitted programs return with their outputs.
+A request submitted with ``capture=n`` keeps the logits its first token and
+its first ``n`` decoded tokens were sampled from (``Request.logits``).
 
 The engine reports through the shared :class:`repro.serve.metrics
 .ServeMetrics` (same surface as the classical tiers): one ``record_batch``
@@ -30,6 +43,7 @@ aggregate ``metrics`` surface stays exactly as before.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import time
@@ -39,6 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import tracing
 from repro.models.transformer import (
     ModelConfig,
     forward_decode,
@@ -48,9 +63,18 @@ from repro.models.transformer import (
 from repro.serve.metrics import ServeMetrics
 from repro.serve.scheduling import AdmissionQueue, SlotPool, bucket_for
 
-__all__ = ["ServeEngine", "Request"]
+__all__ = ["ServeEngine", "Request", "PREFILL_FIELDS", "DECODE_FIELDS"]
 
 _SEQ_KEYS = ("k", "v", "ckv", "kr")       # cache leaves with a sequence axis
+# one record per prefill: start (time.perf_counter), prompt length, bucket,
+# seconds of the forward with the first token's fetch and sampling, seconds
+# of the insert's dispatch
+PREFILL_FIELDS = ("t0", "plen", "bucket", "prefill_s", "insert_s")
+# one record per decode step: start, active slots, cache rows they attend
+# over (sum of pos + 1), seconds of dispatch, fetch and sampling
+DECODE_FIELDS = ("t0", "n_active", "ctx_rows", "forward_s", "fetch_s",
+                 "sample_s")
+_RECORDS = 1 << 16
 
 
 @dataclasses.dataclass
@@ -66,10 +90,18 @@ class Request:
     # AdmissionQueue's ``due``/``next_due_in`` bookkeeping reads.
     deadline: float | None = None
     t_first_token: float | None = None
+    # decode steps whose logits are kept, beside the prefill's last row
+    capture: int = 0
+    logits: list = dataclasses.field(default_factory=list)
 
     @property
     def done(self) -> bool:
         return len(self.tokens) >= self.max_new_tokens
+
+    @property
+    def capturing(self) -> bool:
+        """Whether the next decoded token's logits are kept."""
+        return 0 < len(self.logits) <= self.capture
 
     @property
     def ttft_s(self) -> float | None:
@@ -153,38 +185,84 @@ class ServeEngine:
         self.metrics = ServeMetrics()
         self.metrics_prefill = ServeMetrics()
         self.metrics_decode = ServeMetrics()
+        self.counters = dict.fromkeys(
+            ("decode_steps", "decoded_tokens", "prefill_tokens",
+             "prefill_padded_tokens"), 0)
+        self.expert_counts = (np.zeros((cfg.n_moe_layers, cfg.n_experts),
+                                       np.int64)
+                              if cfg.n_moe_layers else None)
+        self.prefill_records: collections.deque = collections.deque(
+            maxlen=_RECORDS)
+        self.decode_records: collections.deque = collections.deque(
+            maxlen=_RECORDS)
 
     # ------------------------------------------------------------- jit fns
     @functools.cached_property
     def _prefill(self):
-        @jax.jit
-        def fn(params, tokens):
-            logits, caches, _ = forward_full(params, self.cfg, tokens,
-                                             return_cache=True)
-            return logits, caches
-        return fn
+        cfg = self.cfg
+
+        def lm_prefill(params, tokens, plen):
+            """(logits at the last prompt position (Vp,), caches, routed
+            choices per expert of the prompt's tokens)."""
+            real = jnp.arange(tokens.shape[1])[None, :] < plen
+            logits, caches, _, counts = forward_full(
+                params, cfg, tokens, return_cache=True,
+                head_positions=(plen - 1)[None], count_mask=real)
+            return logits[0], caches, counts
+        return jax.jit(lm_prefill)
 
     @functools.cached_property
     def _decode(self):
-        if self._cache_sh is not None:
-            @functools.partial(
-                jax.jit,
-                in_shardings=(self._param_sh, None, self._cache_sh, None),
-                out_shardings=(None, self._cache_sh),
-                donate_argnums=(2,),
-            )
-            def fn(params, token, caches, pos):
-                return forward_decode(params, self.cfg, token, caches, pos)
-            return fn
+        cfg = self.cfg
 
-        @jax.jit
-        def fn(params, token, caches, pos):
-            logits, caches = forward_decode(params, self.cfg, token, caches, pos)
-            return logits, caches
-        return fn
+        def lm_decode(params, token, caches, pos, active):
+            """(greedy tokens, logits, caches, routed choices per expert of
+            the active slots)."""
+            logits, caches, counts = forward_decode(
+                params, cfg, token, caches, pos, count_mask=active)
+            col = jnp.arange(logits.shape[-1])[None, :]
+            masked = jnp.where(col < cfg.vocab_size, logits, -jnp.inf)
+            return jnp.argmax(masked, axis=-1), logits, caches, counts
+
+        if self._cache_sh is not None:
+            return jax.jit(
+                lm_decode,
+                in_shardings=(self._param_sh, None, self._cache_sh, None,
+                              None),
+                out_shardings=(None, None, self._cache_sh, None),
+                donate_argnums=(2,))
+        return jax.jit(lm_decode, donate_argnums=(2,))
+
+    @functools.cached_property
+    def _put(self):
+        def lm_insert(caches, new, slot):
+            """The prompt's cache rows into ``slot``: a ring-buffer leaf
+            (shorter than the prompt) keeps the last rows, each at its
+            position modulo the ring."""
+            def put(key, leaf, row):
+                row = row.astype(leaf.dtype)
+                if key in _SEQ_KEYS and row.shape[2] > leaf.shape[2]:
+                    S, win = row.shape[2], leaf.shape[2]
+                    idx = np.arange(S - win, S)
+                    return leaf.at[:, slot, idx % win].set(row[:, 0, idx])
+                start = (0, slot) + (0,) * (leaf.ndim - 2)
+                return jax.lax.dynamic_update_slice(leaf, row, start)
+            return {k: put(k, caches[k], new[k]) for k in caches}
+
+        if self._cache_sh is not None:
+            return jax.jit(lm_insert, out_shardings=self._cache_sh,
+                           donate_argnums=(0,))
+        return jax.jit(lm_insert, donate_argnums=(0,))
+
+    @functools.cached_property
+    def _row(self):
+        return jax.jit(lambda logits, i: logits[i])
 
     # --------------------------------------------------------- bookkeeping
-    def submit(self, prompt: list[int], max_new_tokens: int = 16) -> int:
+    def submit(self, prompt: list[int], max_new_tokens: int = 16,
+               capture: int = 0) -> int:
+        """Queue a request; returns its id.  ``capture`` keeps the logits of
+        its first token and of its first ``capture`` decoded tokens."""
         prompt = list(prompt)
         if not prompt:
             raise ValueError("empty prompt")
@@ -196,7 +274,8 @@ class ServeEngine:
         now = time.monotonic()
         req = Request(self._next_rid, prompt, max_new_tokens, t_submit=now,
                       deadline=(None if self.prefill_slo_s is None
-                                else now + self.prefill_slo_s))
+                                else now + self.prefill_slo_s),
+                      capture=capture)
         self._queue.push(req)      # QueueFull propagates as backpressure
         self._next_rid += 1
         return req.rid
@@ -209,7 +288,7 @@ class ServeEngine:
             return n
         return bucket_for(n, self.max_len, floor=8)
 
-    def _sample(self, logits: jax.Array) -> int:
+    def _sample(self, logits) -> int:
         lf = np.array(logits, np.float32)        # writable copy
         lf[self.cfg.vocab_size:] = -np.inf       # mask vocab padding
         if self.greedy:
@@ -227,20 +306,23 @@ class ServeEngine:
         sp = self._bucket(plen)
         padded = np.zeros(sp, np.int32)
         padded[:plen] = prompt
-        logits, pcache = self._prefill(self.params, jnp.asarray(padded)[None, :])
-        first = self._sample(logits[0, plen - 1])
-
-        def put(key: str, engine_leaf, new_leaf):
-            if key in _SEQ_KEYS:
-                S = new_leaf.shape[2]
-                win = engine_leaf.shape[2]
-                if S <= win:
-                    return engine_leaf.at[:, slot, :S].set(new_leaf[:, 0])
-                idx = np.arange(S - win, S)
-                return engine_leaf.at[:, slot, idx % win].set(new_leaf[:, 0, idx])
-            return engine_leaf.at[:, slot].set(new_leaf[:, 0])
-
-        self.caches = {k: put(k, self.caches[k], pcache[k]) for k in self.caches}
+        t0 = time.perf_counter()
+        with tracing.span(tracing.LM_PREFILL, plen=plen, bucket=sp):
+            logits, pcache, counts = self._prefill(
+                self.params, jnp.asarray(padded)[None, :], np.int32(plen))
+            lf, counts = jax.device_get((logits, counts))
+            first = self._sample(lf)
+        t1 = time.perf_counter()
+        with tracing.span(tracing.LM_INSERT):
+            self.caches = self._put(self.caches, pcache, np.int32(slot))
+        t2 = time.perf_counter()
+        self.prefill_records.append((t0, plen, sp, t1 - t0, t2 - t1))
+        self.counters["prefill_tokens"] += plen
+        self.counters["prefill_padded_tokens"] += sp - plen
+        if counts is not None:
+            self.expert_counts += counts
+        if req.capture:
+            req.logits.append(np.asarray(lf, np.float32))
         self.pos[slot] = plen
         self.slots.acquire(slot)
         self.last_token[slot] = first
@@ -289,25 +371,58 @@ class ServeEngine:
             return {}
 
         n_active = len(self._slots)
-        t0 = time.perf_counter()
-        logits, self.caches = self._decode(
-            self.params, jnp.asarray(self.last_token), self.caches,
-            jnp.asarray(self.pos),
-        )
-        lg = np.asarray(logits, np.float32)      # _sample copies its own row
-        # np.asarray blocked on the device result, so the window around the
-        # forward is honest device time for this decode batch.
-        self.metrics.record_batch(n_active, time.perf_counter() - t0)
-        out: dict[int, int] = {}
-        for slot, req in list(self._slots.items()):
-            tok = self._sample(lg[slot])         # masks padding + greedy/categorical
-            req.tokens.append(tok)
-            out[req.rid] = tok
-            self.last_token[slot] = tok
-            self.pos[slot] += 1
-            if req.done or self.pos[slot] >= self.max_len - 1:
-                self._retire(slot, req)
+        ctx_rows = int(self.pos[self.active].sum()) + n_active
+        with tracing.span(tracing.LM_DECODE, n_active=n_active):
+            t0 = time.perf_counter()
+            with tracing.span(tracing.LM_DECODE_FORWARD):
+                tok, logits, self.caches, counts = self._decode(
+                    self.params, jnp.asarray(self.last_token), self.caches,
+                    jnp.asarray(self.pos), jnp.asarray(self.active))
+            t1 = time.perf_counter()
+            with tracing.span(tracing.LM_DECODE_FETCH):
+                # one wait for the step; rows leave the device only where
+                # they are needed: every row to sample from them here, the
+                # captured rows beside the device's greedy tokens
+                if self.greedy:
+                    tok, counts = jax.device_get((tok, counts))
+                    rows = {s: np.asarray(self._row(logits, np.int32(s)))
+                            for s, r in self._slots.items() if r.capturing}
+                else:
+                    lg, counts = jax.device_get((logits, counts))
+                    rows = {s: lg[s] for s in self._slots}
+            t2 = time.perf_counter()
+            # the window around dispatch and fetch is honest device time for
+            # this decode batch: the fetch waited for its result
+            self.metrics.record_batch(n_active, t2 - t0)
+            with tracing.span(tracing.LM_DECODE_SAMPLE):
+                out: dict[int, int] = {}
+                for slot, req in list(self._slots.items()):
+                    row = rows.get(slot)
+                    if self.greedy:
+                        tok_s = int(tok[slot])
+                    else:       # masks padding, samples categorically
+                        tok_s = self._sample(row)
+                    if req.capturing:
+                        req.logits.append(np.asarray(row, np.float32))
+                    req.tokens.append(tok_s)
+                    out[req.rid] = tok_s
+                    self.last_token[slot] = tok_s
+                    self.pos[slot] += 1
+                    if req.done or self.pos[slot] >= self.max_len - 1:
+                        self._retire(slot, req)
+            t3 = time.perf_counter()
+        self.decode_records.append((t0, n_active, ctx_rows, t1 - t0, t2 - t1,
+                                    t3 - t2))
+        self.counters["decode_steps"] += 1
+        self.counters["decoded_tokens"] += n_active
+        if counts is not None:
+            self.expert_counts += counts
         return out
+
+    def take_finished(self) -> list[Request]:
+        """The requests retired since the last call, in retirement order."""
+        done, self._finished = self._finished, []
+        return done
 
     # ------------------------------------------------------------ driver
     def run_to_completion(self, max_steps: int = 10_000) -> list[Request]:

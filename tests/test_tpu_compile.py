@@ -170,3 +170,22 @@ def test_served_forward_names_its_programs(name, one_chip, monkeypatch):
     assert text.startswith("HloModule jit_mafia_forward_vmap")
     (call,) = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
     assert call.lstrip().startswith("%mafia_grid_seg0")
+
+
+def test_dropless_expert_layer_compiles_at_published_widths(one_chip):
+    """DeepSeek-V2-Lite's expert layer as the decode step runs it: 128
+    tokens, 64 experts of 2,048 → 1,408, top-6, two shared experts, bf16.
+    The grouped matmuls stay grouped (``ragged-dot``), not a dense matmul
+    over every expert."""
+    from repro.models.layers import Initializer
+    from repro.models.moe import init_moe, moe_dropless
+
+    params = jax.eval_shape(lambda: init_moe(
+        Initializer(jax.random.key(0)), 2048, 1408, 64, n_shared=2,
+        dtype=jnp.bfloat16))
+    place = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    params = jax.tree.map(lambda a: place(a.shape, a.dtype), params)
+    x = place((128, 1, 2048), jnp.bfloat16)
+    fn = jax.jit(functools.partial(moe_dropless, k=6))
+    text = fn.lower(params, x).compile().as_text()
+    assert "ragged-dot" in text
