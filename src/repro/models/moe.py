@@ -19,8 +19,10 @@ top-6; V2-Lite 2 shared + 64 routed, top-6).  The capacity path
 
 The serving path (:func:`moe_dropless`) loses no token: the (token, choice)
 pairs are sorted by expert and each expert's SwiGLU runs over exactly the
-rows it received (``jax.lax.ragged_dot``, a grouped matmul), so a batch that
-routes unevenly costs time and never gate mass.
+rows it received (:func:`repro.kernels.grouped_matmul.grouped_matmul`), so a
+batch that routes unevenly costs time and never gate mass.  The grouped
+matmuls take the whole stack of the model's expert weights and the layer's
+index, and read that layer's experts in place.
 """
 
 from __future__ import annotations
@@ -30,6 +32,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.grouped_matmul import grouped_matmul
 from repro.models.layers import Initializer, he_init, mlp_swiglu
 from repro.sharding.ctx import shard_act
 
@@ -177,18 +180,21 @@ def moe_dropless(
     x: jax.Array,               # (B, S, D)
     *,
     k: int,
+    layer: jax.Array | int = 0,
     norm_topk_prob: bool = False,
     routed_scaling_factor: float = 1.0,
     count_mask: jax.Array | None = None,   # (B, S): tokens to count
 ) -> tuple[jax.Array, jax.Array]:
     """Dropless MoE: every token's ``k`` chosen experts, and only those.
 
-    The T·k (token, choice) rows are sorted by expert; a grouped matmul
-    (``ragged_dot``) runs each expert's SwiGLU over its own contiguous rows;
-    the rows are put back in token order, weighted by their gates in float32
-    and summed; the shared experts are added.  No capacity, no token dropped.
-    Returns (output (B, S, D), routed choices per expert (E,) over the
-    tokens ``count_mask`` keeps)."""
+    The T·k (token, choice) rows are sorted by expert; a grouped matmul runs
+    each expert's SwiGLU over its own contiguous rows; the rows are put back
+    in token order, weighted by their gates in float32 and summed; the
+    shared experts are added.  No capacity, no token dropped.  The routed
+    experts' weights are one layer's, (E, K, N), or the stack of every MoE
+    layer's, (L, E, K, N), of which ``layer`` is this one: they are read in
+    place, never sliced out.  Returns (output (B, S, D), routed choices per
+    expert (E,) over the tokens ``count_mask`` keeps)."""
     B, S, D = x.shape
     E = p["router"].shape[-1]
     T = B * S
@@ -199,13 +205,17 @@ def moe_dropless(
     order = jnp.argsort(flat_e, stable=True)
     rows = xt[order // k]                                       # sorted rows
     sizes = jnp.zeros((E,), jnp.int32).at[flat_e].add(1)
-    g = jax.lax.ragged_dot(rows, p["w_gate"].astype(dt), sizes,
-                           preferred_element_type=jnp.float32)
-    u = jax.lax.ragged_dot(rows, p["w_up"].astype(dt), sizes,
-                           preferred_element_type=jnp.float32)
+
+    def experts(rows, name):
+        w = p[name]
+        if w.ndim == 3:
+            return grouped_matmul(rows, w[None], 0, sizes)
+        return grouped_matmul(rows, w, layer, sizes)
+
+    g = experts(rows, "w_gate")
+    u = experts(rows, "w_up")
     h = (jax.nn.silu(g) * u).astype(dt)
-    y = jax.lax.ragged_dot(h, p["w_down"].astype(dt), sizes,
-                           preferred_element_type=jnp.float32)  # (T·k, D)
+    y = experts(h, "w_down")                                    # (T·k, D)
     back = jnp.argsort(order)                                   # unsort
     y = y[back].reshape(T, k, D)
     out = jnp.sum(y * top_g[..., None], axis=1)     # elementwise: float32

@@ -327,15 +327,40 @@ def _maybe_remat(fn, cfg: ModelConfig):
     return fn
 
 
-def _ffn(cfg: ModelConfig, blk, h, count_mask):
+_EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
+
+
+def _scanned_experts(cfg: ModelConfig, blocks):
+    """A layer stack as its scan takes it: (the blocks it slices, the routed
+    expert weights it closes over whole or None).  On the dropless path the
+    grouped matmul is a custom call, which takes no fused slice: a sliced
+    layer of experts would be copied out of the stack in every layer of
+    every call, so they stay whole and are read in place by layer index."""
+    if cfg.moe_impl != "dropless" or "moe" not in blocks:
+        return blocks, None
+    moe = dict(blocks["moe"])
+    experts = {n: moe.pop(n) for n in _EXPERT_WEIGHTS}
+    return {**blocks, "moe": moe}, experts
+
+
+def _with_experts(blk, experts):
+    """A scanned block with the whole expert stacks put back."""
+    if experts is None:
+        return blk
+    return {**blk, "moe": {**blk["moe"], **experts}}
+
+
+def _ffn(cfg: ModelConfig, blk, h, count_mask, layer=0):
     """The block's FFN: (output, aux loss, routed choices per expert or
-    None).  Dense blocks have no experts."""
+    None).  Dense blocks have no experts; ``layer`` is the block's index in
+    its stack, which the dropless path's whole expert stacks are read at."""
     if "moe" not in blk:
         return mlp_swiglu(blk["mlp"], h), jnp.zeros((), jnp.float32), None
     k = cfg.experts_per_token
     if cfg.moe_impl == "dropless":
         f, counts = moe_mod.moe_dropless(
-            blk["moe"], h, k=k, norm_topk_prob=cfg.norm_topk_prob,
+            blk["moe"], h, k=k, layer=layer,
+            norm_topk_prob=cfg.norm_topk_prob,
             routed_scaling_factor=cfg.routed_scaling_factor,
             count_mask=count_mask)
         return f, jnp.zeros((), jnp.float32), counts
@@ -349,7 +374,7 @@ def _ffn(cfg: ModelConfig, blk, h, count_mask):
 
 
 def _attn_block_full(cfg: ModelConfig, blk, x, cos, sin, window,
-                     count_mask=None):
+                     count_mask=None, layer=0):
     h = rms_norm(x, blk["norm1"], cfg.norm_eps)
     if cfg.use_mla:
         a, cache = attn.mla_prefill(blk["attn"], h, cos, sin, kv_chunk=cfg.kv_chunk,
@@ -361,7 +386,7 @@ def _attn_block_full(cfg: ModelConfig, blk, x, cos, sin, window,
                                     probs_bf16=cfg.attn_probs_bf16)
     x = x + a
     h = rms_norm(x, blk["norm2"], cfg.norm_eps)
-    f, aux, counts = _ffn(cfg, blk, h, count_mask)
+    f, aux, counts = _ffn(cfg, blk, h, count_mask, layer)
     return x + f, cache, aux, counts
 
 
@@ -426,19 +451,22 @@ def forward_full(
         cos, sin = rope_table(S, cfg.rope_dim, cfg.rope_theta,
                               scaling=cfg.rope_scaling)
 
-        def body(carry, blk):
-            h, aux = carry
+        def body(experts, carry, blk):
+            h, aux, j = carry
             h2, cache, a, c = _maybe_remat(
-                lambda b, hh: _attn_block_full(cfg, b, hh, cos, sin, window,
-                                               count_mask), cfg
-            )(blk, h)
+                lambda b, hh, jj: _attn_block_full(cfg, b, hh, cos, sin,
+                                                   window, count_mask, jj),
+                cfg
+            )(_with_experts(blk, experts), h, j)
             out = cache if return_cache else None
-            return (h2, aux + a), (out, c)
+            return (h2, aux + a, j + 1), (out, c)
 
         cache_parts, count_parts = [], []
         for blocks in _stacks(params):
-            (x, aux_total), (cache, c) = jax.lax.scan(body, (x, aux_total),
-                                                      blocks)
+            blocks, experts = _scanned_experts(cfg, blocks)
+            (x, aux_total, _), (cache, c) = jax.lax.scan(
+                functools.partial(body, experts),
+                (x, aux_total, jnp.int32(0)), blocks)
             cache_parts.append(cache)
             if c is not None:
                 count_parts.append(c)
@@ -583,8 +611,9 @@ def _mla_decode_stacks(params, cfg: ModelConfig, x, caches, pos, cos, sin,
     b = jnp.arange(x.shape[0])
     ckv, kr = caches["ckv"], caches["kr"]
 
-    def body(carry, blk):
+    def body(experts, first, carry, blk):
         h, ckv, kr, i = carry
+        blk = _with_experts(blk, experts)
         hn = rms_norm(h, blk["norm1"], cfg.norm_eps)
         q_lat, q_rope, c_new, k_new = attn.mla_decode_inputs(
             blk["attn"], hn, cos, sin, cfg.norm_eps)
@@ -594,13 +623,15 @@ def _mla_decode_stacks(params, cfg: ModelConfig, x, caches, pos, cos, sin,
                                    pos, cfg.mla_scale, h.dtype)
         h = h + a
         f, _, c = _ffn(cfg, blk, rms_norm(h, blk["norm2"], cfg.norm_eps),
-                       mask)
+                       mask, i - first)
         return (h + f, ckv, kr, i + 1), c
 
     counts, layer = [], jnp.int32(0)
     for blocks in _stacks(params):
-        (x, ckv, kr, layer), c = jax.lax.scan(body, (x, ckv, kr, layer),
-                                              blocks)
+        blocks, experts = _scanned_experts(cfg, blocks)
+        (x, ckv, kr, layer), c = jax.lax.scan(
+            functools.partial(body, experts, layer), (x, ckv, kr, layer),
+            blocks)
         if c is not None:
             counts.append(c)
     return x, {"ckv": ckv, "kr": kr}, counts
@@ -608,15 +639,17 @@ def _mla_decode_stacks(params, cfg: ModelConfig, x, caches, pos, cos, sin,
 
 def _gqa_decode_stacks(params, cfg: ModelConfig, x, caches, pos, cos, sin,
                        mask):
-    def body(h, xs):
+    def body(experts, carry, xs):
+        h, j = carry
         blk, c0, c1 = xs
+        blk = _with_experts(blk, experts)
         hn = rms_norm(h, blk["norm1"], cfg.norm_eps)
         a, (c0, c1) = attn.gqa_decode(blk["attn"], hn, c0, c1, pos, cos, sin,
                                       window=cfg.attn_window)
         h = h + a
         f, _, c = _ffn(cfg, blk, rms_norm(h, blk["norm2"], cfg.norm_eps),
-                       mask)
-        return h + f, (c0, c1, c)
+                       mask, j)
+        return (h + f, j + 1), (c0, c1, c)
 
     stacks = _stacks(params)
     counts, new_k, new_v, layer = [], [], [], 0
@@ -625,7 +658,9 @@ def _gqa_decode_stacks(params, cfg: ModelConfig, x, caches, pos, cos, sin,
         k, v = caches["k"], caches["v"]
         if len(stacks) > 1:
             k, v = k[layer:layer + n], v[layer:layer + n]
-        x, (k, v, c) = jax.lax.scan(body, x, (blocks, k, v))
+        blocks, experts = _scanned_experts(cfg, blocks)
+        (x, _), (k, v, c) = jax.lax.scan(functools.partial(body, experts),
+                                         (x, jnp.int32(0)), (blocks, k, v))
         new_k.append(k)
         new_v.append(v)
         if c is not None:

@@ -266,6 +266,46 @@ def test_dense_then_moe_stacks():
     assert float(jnp.abs(other - logits).max()) > 1e-3
 
 
+@pytest.mark.parametrize("attention", ["mla", "gqa"])
+def test_moe_layers_read_their_own_experts(attention, monkeypatch):
+    """One dense layer, then three MoE layers with distinct weights: the
+    prefill and the decode step, whose grouped matmuls read each layer's
+    experts in place from the whole stack by its index within the MoE
+    stack, give the logits of the same model with each layer's experts
+    sliced out of the stack for it.  Reordering the layers' experts changes
+    them, so an index that counts the dense layer, or none, would show."""
+    cfg = dataclasses.replace(SMOKE, n_layers=4)
+    if attention == "gqa":
+        cfg = dataclasses.replace(cfg, use_mla=False, n_kv_heads=2,
+                                  d_rope=0, rope_scaling=None)
+    params = T.init_params(cfg, jax.random.key(4))
+    assert params["blocks"]["moe"]["w_up"].shape[0] == 3
+    toks = jnp.asarray(RNG.integers(0, cfg.vocab_size, (2, 10)), jnp.int32)
+
+    def run(params):
+        full, cache, _ = T.forward_full(params, cfg, toks[:, :-1],
+                                        return_cache=True)
+        cache = jax.tree.map(
+            lambda c: jnp.pad(c, [(0, 1) if i == 2 else (0, 0)
+                                  for i in range(c.ndim)]), cache)
+        step, _ = T.forward_decode(params, cfg, toks[:, -1], cache,
+                                   jnp.full((2,), 9, jnp.int32))
+        return np.asarray(full), np.asarray(step)
+
+    in_place = run(params)
+    with monkeypatch.context() as m:      # the scans slice the experts
+        m.setattr(T, "_scanned_experts", lambda cfg, blocks: (blocks, None))
+        sliced = run(params)
+    for got, want in zip(in_place, sliced):
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    moe = params["blocks"]["moe"]
+    reordered = {**moe, **{n: moe[n][jnp.array([2, 0, 1])]
+                           for n in ("w_gate", "w_up", "w_down")}}
+    other = run({**params, "blocks": {**params["blocks"], "moe": reordered}})
+    for got, moved in zip(in_place, other):
+        assert np.abs(got - moved).max() > 1e-3
+
+
 def test_last_position_head_is_full_heads_row():
     params = T.init_params(SMOKE, jax.random.key(2))
     toks = jnp.asarray(RNG.integers(0, SMOKE.vocab_size, (2, 9)), jnp.int32)

@@ -293,3 +293,46 @@ def test_decode_attention_kernel_full_lengths():
     out = decode_attention(q, k, v, lens, bk=16)
     expect = ref.decode_attention_ref(q, k, v, lens)
     np.testing.assert_allclose(out, expect, rtol=2e-4, atol=2e-4)
+
+
+# ------------------------------------------------------- grouped matmul
+def _sizes(case: str, m: int, e: int) -> np.ndarray:
+    if case == "one_expert":
+        return np.eye(e, dtype=np.int32)[e // 2] * m
+    sizes = np.bincount(RNG.integers(0, e, m), minlength=e).astype(np.int32)
+    if case == "empty_experts":         # three experts no row chose
+        sizes[[0, 3, e - 1]] = 0
+        sizes[1] += m - sizes.sum()
+    return sizes
+
+
+@pytest.mark.parametrize("case,m", [
+    ("empty_experts", 300),
+    ("one_expert", 256),
+    ("tail_tile", 333),     # 2 whole 128-row tiles and a part of one
+    ("decode", 128 * 6),    # 128 slots, top-6
+    ("prefill", 256 * 6),   # a 256-token prompt, top-6
+])
+def test_grouped_matmul_reads_each_layer_of_the_stack(case, m):
+    """Each layer of a 3-layer expert stack, read in place, gives what a
+    grouped matmul on that layer's sliced weights gives, and a float64
+    reference."""
+    from repro.kernels.grouped_matmul import grouped_matmul
+
+    L, E, K, N = 3, 8, 64, 40
+    sizes = _sizes(case, m, E)
+    assert sizes.sum() == m
+    lhs = RNG.normal(size=(m, K)).astype(np.float32)
+    rhs = RNG.normal(size=(L, E, K, N)).astype(np.float32)
+    expert = np.repeat(np.arange(E), sizes)
+    for layer in range(L):
+        got = np.asarray(grouped_matmul(jnp.asarray(lhs), jnp.asarray(rhs),
+                                        layer, jnp.asarray(sizes)))
+        sliced = jax.lax.ragged_dot(jnp.asarray(lhs), jnp.asarray(rhs[layer]),
+                                    jnp.asarray(sizes),
+                                    precision=jax.lax.Precision.HIGHEST)
+        want = np.einsum("mk,mkn->mn", lhs.astype(np.float64),
+                         rhs[layer][expert].astype(np.float64))
+        np.testing.assert_allclose(got, np.asarray(sliced), rtol=1e-5,
+                                   atol=1e-4)
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-4)
